@@ -328,6 +328,11 @@ def main(argv=None) -> int:
                          "burst walls)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    # the launched server enables the cache in its own process; this
+    # sets the same directory here without starting a JAX backend, so
+    # the parent never holds the chip its child needs
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.launch == (args.host is not None):
         ap.error("pass exactly one of --launch or --host/--port")

@@ -21,6 +21,8 @@ def main() -> None:
     ap.add_argument("--only", default="fig4,fig3,engine,serving,roofline")
     ap.add_argument("--budget-s", type=float, default=90.0)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     which = set(args.only.split(","))
     rows: list[tuple] = []
     t0 = time.time()

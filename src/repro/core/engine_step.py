@@ -40,13 +40,14 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..patterns.store import (MASK_WORDS, PatternStore, PatternStoreBank,
                               StoreCounters, hash_insert, hash_probe)
 
 N_PAD = 64              # padded query size
-FULL = jnp.uint32(0xFFFFFFFF)
+FULL = np.uint32(0xFFFFFFFF)   # host constant: import starts no backend
 
 
 class GraphArrays(NamedTuple):
@@ -373,7 +374,7 @@ def refine_eq2_mq(g: GraphArrays, qb: QueryBank, query_slot: jax.Array,
             w = acc0.shape[1]
             out = refine_bitmap_rows_hier(
                 g.adj_summary, g.chunk_ptr, g.chunk_id, g.chunk_data,
-                g.chunk_pad.shape[0], acc0, frontier, active,
+                acc0, frontier, active,
                 interpret=(backend == "pallas_interpret"),
                 dma_depth=dma_depth)
             return out[:, :w].astype(jnp.uint32)

@@ -68,12 +68,13 @@ class FaultInjected(RuntimeError):
 
 
 # exception types the dispatch retry loop treats as recoverable: the
-# injected fault plus whatever runtime error the JAX backend surfaces
-try:                                                 # pragma: no cover
-    from jax.errors import JaxRuntimeError as _JaxRuntimeError
-    DISPATCH_ERRORS: tuple = (FaultInjected, _JaxRuntimeError)
-except Exception:                                    # pragma: no cover
-    DISPATCH_ERRORS = (FaultInjected,)
+# injected fault only, which stands for a fault while a program runs.
+# Dispatch is asynchronous, so an error the jitted call itself raises
+# comes from tracing, lowering or compiling the program (or allocating
+# its buffers): a retry fails the same way, and replaying the query on
+# the host would hide a device that cannot run the program. Such an
+# error propagates and stops the run.
+DISPATCH_ERRORS: tuple = (FaultInjected,)
 
 
 @dataclasses.dataclass(frozen=True)

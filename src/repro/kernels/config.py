@@ -9,7 +9,8 @@ decides how every op lowers:
   * ``"jnp"``              — pure-jnp oracle path (``ref.py``); fastest on
                              CPU and what the dry-run lowers by default.
   * ``"pallas_interpret"`` — Pallas kernel bodies interpreted on CPU (the
-                             kernel-validation mode used by the tests).
+                             kernel-validation mode used by the tests;
+                             refused where JAX's backend is not the CPU).
   * ``"pallas"``           — compiled TPU kernels (target hardware).
 
 Resolution order: explicit ``backend=`` argument > ``set_backend()`` >
@@ -43,11 +44,14 @@ DEFAULT_DMA_DEPTH = 2    # in-flight chunk copies in the HBM refine
                          # kernel's double-buffered pipeline
 
 # Dense/hierarchical threshold: below this many data-graph vertices the
-# whole-VMEM dense kernel is the fast path (the padded adjacency block
-# fits comfortably — 8K vertices is 8 MB); at or above it the adjacency
-# stays in HBM and the hierarchical kernel pages live chunks into VMEM
-# scratch (DESIGN.md §2). A tuning record or kernel_param_scope override
-# ("hbm_adjacency") wins over the threshold.
+# dense kernel holds the whole padded adjacency as one single-buffered
+# VMEM block (16,383 vertices is 32 MiB of the v5e's 128 MiB VMEM; the
+# TPU compiler takes 28,672 vertices, 98 MiB, and refuses 30,720);
+# at or above it the adjacency stays in HBM and the hierarchical kernel
+# copies only live chunks (DESIGN.md §2). tests/test_tpu_compile.py
+# compiles the dense kernel at the largest size this sends to it. A
+# tuning record or kernel_param_scope override ("hbm_adjacency") wins
+# over the threshold.
 HBM_ADJACENCY_MIN_VERTICES = 16384
 
 # scope-local kernel parameter overrides (kernel_param_scope) — the
@@ -164,13 +168,23 @@ def use_hbm_adjacency(backend: str | None = None,
 
 
 def resolve(backend: str | None) -> str:
-    """An explicit per-call backend wins; None means the global config."""
-    if backend is None:
-        return get_backend()
-    if backend not in BACKENDS:
-        raise ValueError(f"unknown kernel backend {backend!r}; "
+    """An explicit per-call backend wins; None means the global config.
+
+    ``"pallas_interpret"`` resolves only where JAX's default backend is
+    the CPU: on an accelerator it would run every kernel in the host
+    interpreter and report that as the device's speed."""
+    name = get_backend() if backend is None else backend
+    if name not in BACKENDS:
+        raise ValueError(f"unknown kernel backend {name!r}; "
                          f"choose one of {BACKENDS}")
-    return backend
+    if name == "pallas_interpret":
+        import jax
+        platform = jax.default_backend()
+        if platform != "cpu":
+            raise ValueError(
+                f"kernel backend 'pallas_interpret' on a {platform!r} "
+                "device; use 'pallas' to compile the kernels for it")
+    return name
 
 
 def interpret_mode(backend: str | None) -> bool:

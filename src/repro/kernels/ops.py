@@ -74,8 +74,7 @@ def refine_bitmap_rows_hier_op(summary, chunk_ptr, chunk_id, chunk_data,
             summary, chunk_ptr, chunk_id, chunk_data, int(kmax),
             cand_rows, frontier, active)
     out = _refine_rows_hier_pallas(summary, chunk_ptr, chunk_id,
-                                   chunk_data, int(kmax), cand_rows,
-                                   frontier, active,
+                                   chunk_data, cand_rows, frontier, active,
                                    interpret=interpret_mode(backend),
                                    dma_depth=dma_depth)
     return out[:, :w].astype(jnp.uint32)

@@ -3,8 +3,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-FULL_U32 = jnp.uint32(0xFFFFFFFF)
+FULL_U32 = np.uint32(0xFFFFFFFF)     # host constant: no backend at import
 
 
 def refine_bitmap_ref(adj_bitmap: jax.Array, cand_row: jax.Array,

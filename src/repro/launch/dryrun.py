@@ -56,8 +56,10 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: pathlib.Path,
             tokens = c.dims["global_batch"]
             model_flops = 2.0 * cfg.n_active_params() * tokens
 
+    # the production meshes are pods of v5e chips: the roofline terms
+    # assume that chip whatever device compiled the cell
     roof = analyze(arch, shape, mesh_name, chips, compiled,
-                   model_flops=model_flops)
+                   device_kind="TPU v5 lite", model_flops=model_flops)
     mem_txt = None
     try:
         mem_txt = str(compiled.memory_analysis())

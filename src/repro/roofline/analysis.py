@@ -18,10 +18,34 @@ from __future__ import annotations
 import dataclasses
 import re
 
-# TPU v5e-class hardware constants (per chip)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-LINK_BW = 50e9               # bytes/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float             # bf16 FLOP/s
+    hbm_bw: float            # HBM bytes/s
+    link_bw: float           # bytes/s per ICI link
+
+
+# Published per-chip peaks keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM
+# at 819 GB/s, 1,600 Gbit/s of interchip interconnect over 4 links
+# (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; a kind without published peaks in
+    :data:`PEAKS` is an error, never another chip's numbers."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add "
+            f"them to PEAKS with their source (known: {sorted(PEAKS)})"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -76,6 +100,7 @@ class Roofline:
     shape: str
     mesh: str
     chips: int
+    device_kind: str         # key of PEAKS: the chip the terms assume
     hlo_flops: float
     hlo_bytes: float
     coll_bytes: float
@@ -90,15 +115,15 @@ class Roofline:
 
     @property
     def t_compute(self) -> float:
-        return self.hlo_flops / (PEAK_FLOPS)
+        return self.hlo_flops / chip_peaks(self.device_kind).flops
 
     @property
     def t_memory(self) -> float:
-        return self.hlo_bytes / (HBM_BW)
+        return self.hlo_bytes / chip_peaks(self.device_kind).hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / (LINK_BW)
+        return self.coll_bytes / chip_peaks(self.device_kind).link_bw
 
     @property
     def bottleneck(self) -> str:
@@ -115,7 +140,7 @@ class Roofline:
     def to_dict(self) -> dict:
         return {
             "arch": self.arch, "shape": self.shape, "mesh": self.mesh,
-            "chips": self.chips,
+            "chips": self.chips, "device_kind": self.device_kind,
             "hlo_flops_per_device": self.hlo_flops,
             "hlo_bytes_per_device": self.hlo_bytes,
             "coll_bytes_per_device": self.coll_bytes,
@@ -133,7 +158,9 @@ class Roofline:
 
 
 def analyze(arch: str, shape: str, mesh_name: str, chips: int,
-            compiled, model_flops: float | None = None) -> Roofline:
+            compiled, device_kind: str,
+            model_flops: float | None = None) -> Roofline:
+    chip_peaks(device_kind)          # an unknown kind fails here
     from .hlo_cost import analyze_hlo_text
     hlo = compiled.as_text()
     hc = analyze_hlo_text(hlo)       # loop-aware (scan bodies x trip count)
@@ -161,7 +188,7 @@ def analyze(arch: str, shape: str, mesh_name: str, chips: int,
     except Exception:
         pass
     return Roofline(arch=arch, shape=shape, mesh=mesh_name, chips=chips,
-                    hlo_flops=flops, hlo_bytes=byts,
+                    device_kind=device_kind, hlo_flops=flops, hlo_bytes=byts,
                     coll_bytes=total_coll, coll_counts=coll,
                     model_flops=model_flops, mem_per_device=mem,
                     custom_call_bytes=float(hc.custom_call_bytes),

@@ -15,7 +15,9 @@ front of the in-process engine.
   token buckets, weighted fair queueing, bounded-queue load shedding;
 * :mod:`repro.server.metrics` — the ``/metrics`` + ``/slo`` exporter;
 * :mod:`repro.server.client` — the stdlib blocking/streaming client
-  used by tests, examples and ``benchmarks/load_bench.py``.
+  used by tests, examples and ``benchmarks/load_bench.py``. Importing
+  it (or this package) starts no JAX backend; ``MatchServer`` loads
+  the engine on first access.
 
 Launch:  ``python -m repro.server.launch --graph ba --port 8421``
 """
@@ -23,7 +25,6 @@ from .admission import AdmissionController, TenantConfig
 from .client import ServeClient
 from .protocol import (ProtocolError, WIRE_VERSION, decode_event,
                        decode_query, encode_event, encode_query)
-from .server import MatchServer
 from .server_args import ServerArgs
 
 __all__ = [
@@ -31,3 +32,13 @@ __all__ = [
     "ProtocolError", "WIRE_VERSION", "decode_event", "decode_query",
     "encode_event", "encode_query", "MatchServer", "ServerArgs",
 ]
+
+
+def __getattr__(name):
+    # MatchServer pulls in the engine, whose first device array starts
+    # a JAX backend — a process that only imports the client (a load
+    # generator) must not take the chip from its server
+    if name == "MatchServer":
+        from .server import MatchServer
+        return MatchServer
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -2,7 +2,8 @@
 
     python -m repro.server.launch --graph ba --graph-n 512 --port 8421
 
-Builds the data graph, constructs the engine (knobs resolved through
+Turns on the persistent compilation cache (``repro.compile_cache``),
+builds the data graph, constructs the engine (knobs resolved through
 ``MatchOptions`` > tuning cache > built-in, DESIGN.md §9), warms the
 jit cache, then announces readiness on stdout with one machine-parseable
 line:
@@ -14,7 +15,11 @@ traffic). SIGTERM/SIGINT trigger a graceful drain: new requests are
 refused with a typed ``draining`` event, queued + resident queries run
 to their terminal status (bounded by ``--drain-timeout-s``, then
 cancelled through the eviction path), the final SLO report is flushed
-to stderr, and the process exits 0.
+to stderr, and the process exits 0. A warmup that fails (a device
+program that does not compile, a warmup query ending in ``error``, a
+fault counter that moved) or an engine step that raises while serving
+ends the process with a non-zero exit code and no READY line / no
+further service.
 """
 from __future__ import annotations
 
@@ -24,6 +29,7 @@ import signal
 import sys
 import time
 
+from ..compile_cache import enable_compile_cache
 from .server import MatchServer, _jsonify
 from .server_args import ServerArgs
 
@@ -44,6 +50,7 @@ def main(argv=None) -> int:
         if not ns.quiet:
             print(f"[repro-server] {msg}", file=sys.stderr, flush=True)
 
+    log(f"compile cache: {enable_compile_cache()}")
     t0 = time.perf_counter()
     log(f"building data graph: {args.graph} "
         f"(n={args.graph_n}, seed={args.graph_seed})")

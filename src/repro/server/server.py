@@ -122,6 +122,8 @@ class MatchServer:
             tenants, default, on_shed=self._on_admission_shed)
         self._live: dict[int, _ServeRequest] = {}
         self.baseline_qps: float | None = None   # set by warmup()
+        # what stopped the engine thread (serve_forever re-raises it)
+        self.error: BaseException | None = None
         # generator recipe for the resident graph: build_graph is
         # deterministic in these, so a remote client can reconstruct
         # the identical graph and generate valid queries against it
@@ -170,7 +172,12 @@ class MatchServer:
         bursts to the next power of two — under live traffic requests
         arrive in bursts of every size, and each uncompiled variant
         would cost its tenant a ~100ms stall). The warmup queries'
-        latencies are scrubbed from the SLO tallies afterwards."""
+        latencies are scrubbed from the SLO tallies afterwards.
+
+        Raises when a warmup query ends in ``error`` or a fault counter
+        moved: a server whose engine cannot run its own warmup must not
+        announce readiness (a program that fails to compile raises out
+        of the warmup batch itself)."""
         if self.args.warmup_queries <= 0:
             return
         from ..data.graph_gen import query_set
@@ -204,8 +211,14 @@ class MatchServer:
                 self.qserver.submit_batch(batch)
                 qps = len(batch) / (time.perf_counter() - tb)
                 self.baseline_qps = max(self.baseline_qps or 0.0, qps)
-        # warmup traffic must not pollute the serving SLO percentiles
         q = self.qserver
+        moved = {k: v for k, v in (sch.fault_counters.items()
+                                   if sch is not None else ()) if v}
+        if q.n_errors or moved:
+            raise RuntimeError(
+                f"warmup failed: {q.n_errors} warmup queries ended in "
+                f"'error', fault counters moved: {moved}")
+        # warmup traffic must not pollute the serving SLO percentiles
         q.latencies.clear()
         q.ttfes.clear()
         q.n_timeouts = q.n_cancelled = q.n_errors = 0
@@ -225,11 +238,15 @@ class MatchServer:
         self._http_thread.start()
 
     def serve_forever(self) -> None:
-        """Blocking run: returns after a drain completes."""
+        """Blocking run: returns after a drain completes, and raises
+        if the engine thread failed."""
         self.start()
         self._drained.wait()
         self.httpd.shutdown()
         self._http_thread.join(timeout=10)
+        if self.error is not None:
+            raise RuntimeError("engine failed; server stopped") \
+                from self.error
 
     def begin_drain(self) -> None:
         """Graceful shutdown: stop admitting new wire requests, finish
@@ -262,6 +279,9 @@ class MatchServer:
         Returns the live :class:`_ServeRequest`, or a terminal error
         event dict when the request never became a query."""
         self.metrics.bump("requests_total")
+        if self.error is not None:
+            return protocol.error_event(
+                f"engine failed: {self.error!r}", code="engine-failed")
         if self._draining.is_set():
             self.metrics.bump("draining_rejects")
             return protocol.error_event(
@@ -311,8 +331,9 @@ class MatchServer:
             if not session.idle:
                 try:
                     did = session.step() or did
-                except Exception as e:      # pragma: no cover - belt
-                    self.log(f"engine step failed: {e!r}")
+                except Exception as e:      # noqa: BLE001 — stop serving
+                    self._engine_failed(e)
+                    return
             did = self._deliver() or did
             now = time.perf_counter()
             if now - self._t_report >= self.args.metrics_refresh_s:
@@ -333,6 +354,22 @@ class MatchServer:
             if not did:
                 self._work.wait(timeout=self.args.idle_poll_s)
                 self._work.clear()
+
+    def _engine_failed(self, exc: Exception) -> None:
+        """The engine raised — a device program that fails to compile,
+        or any fault the scheduler's retry does not cover. Its state is
+        no longer trusted: every live and queued request ends with
+        status ``error``, the listener stops, and :meth:`serve_forever`
+        re-raises so the process exits non-zero."""
+        self.error = exc
+        self.log(f"engine step failed: {exc!r}")
+        self._draining.set()
+        reqs = list(self._live.values()) + self.admission.pending_items()
+        for req in reqs:
+            req.push_done(req._terminal("error", timed_out=False,
+                                        error=f"engine failed: {exc!r}"))
+        self._live.clear()
+        self._drained.set()
 
     def _admit_ready(self) -> bool:
         """Pull WFQ-ordered admissible requests into the engine until it

@@ -46,13 +46,10 @@ def quantize_vertices(n_vertices: int) -> int:
 
 def device_kind() -> str:
     """Normalized accelerator kind of the default jax device ("cpu",
-    "tpu-v4", ...); "unknown" when jax is unavailable (the cache module
-    stays importable without an accelerator runtime)."""
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind
-    except Exception:                              # pragma: no cover
-        return "unknown"
+    "tpu-v5-lite", ...). A device JAX cannot read raises: a record keyed
+    by a guessed kind would hand one chip's tuning to another."""
+    import jax
+    kind = jax.devices()[0].device_kind
     return str(kind).strip().lower().replace(" ", "-")
 
 

@@ -18,13 +18,12 @@ invalid points *before* anything compiles:
   this with a deliberately odd block height);
 * a VMEM budget at the given ``(V, W)`` shape: the dense refine kernel
   (``hbm_adjacency=0``) holds the whole padded adjacency bitmap plus
-  one candidate/output row block in VMEM, so points whose working set
+  its candidate/output row blocks in VMEM, so points whose working set
   exceeds the budget are rejected with a reason instead of failing at
   compile time. The hierarchical variant (``hbm_adjacency=1``) leaves
-  the adjacency in HBM and only budgets its VMEM scratch — the chunk-id
-  window plus ``dma_depth`` in-flight chunks — so large-V points stay
-  admissible there and the dense rejection explains *why* the layout
-  switches;
+  the adjacency in HBM and only budgets its row blocks, so large-V
+  points stay admissible there and the dense rejection explains *why*
+  the layout switches;
 * hierarchical layout knobs: ``chunk_words`` must be a power of two in
   [1, 128] (the summary packs one bit per chunk into u32 words and the
   kernel slices chunk-aligned word windows), ``dma_depth >= 1``.
@@ -49,10 +48,14 @@ __all__ = ["CandidateConfig", "TunableSpace", "WorkloadShape",
 # tests/test_tuning.py.
 PROBE = 8
 
-# Conservative per-core VMEM budget for the refine kernel's resident
-# working set (real TPUs have ~16 MB; leave headroom for the compiler's
-# own buffers and the scalar-prefetch operands).
-DEFAULT_VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+# VMEM budget for the refine kernel's resident working set. A v5e core
+# has 128 MiB of VMEM; compiled for it, the dense kernel took a 98 MiB
+# adjacency block (28,672 vertices) and was refused at 113 MiB (30,720
+# vertices), so 96 MiB leaves the compiler its own buffers.
+DEFAULT_VMEM_BUDGET_BYTES = 96 * 1024 * 1024
+
+# wave rows per grid step of the hier kernel (bitmap_refine.HIER_ROWS)
+HIER_ROWS = 8
 
 # The knob schema the cache's staleness hash covers: names, domains and
 # the constraint version. Bump ``constraints`` whenever a validity rule
@@ -125,28 +128,23 @@ class CandidateConfig:
 
 def refine_vmem_bytes(shape: WorkloadShape, block_f: int) -> int:
     """Resident VMEM bytes of the dense refine kernel at ``shape``: the
-    whole padded adjacency block plus the candidate and output row
-    blocks (int32 words), mirroring ``bitmap_refine``'s padding rules."""
+    whole padded adjacency block (single-buffered) plus the candidate
+    and output row blocks (two pipeline buffers each, int32 words),
+    mirroring ``bitmap_refine``'s padding rules."""
     w_pad = max(128, ((shape.w + 127) // 128) * 128)
     v_pad = ((shape.v + 7) // 8) * 8
     adj = v_pad * w_pad * 4
-    row_blocks = 2 * block_f * w_pad * 4        # cand block + out block
+    row_blocks = 2 * 2 * block_f * w_pad * 4    # cand + out, 2 buffers
     return adj + row_blocks
 
 
-def refine_hier_vmem_bytes(shape: WorkloadShape, chunk_words: int,
-                           dma_depth: int) -> int:
+def refine_hier_vmem_bytes(shape: WorkloadShape) -> int:
     """Resident VMEM bytes of the *hierarchical* refine kernel: the
-    adjacency stays in HBM; VMEM holds one candidate + mask + output row
-    (w_pad words each), the row's chunk-id window (worst case every
-    chunk stored: ceil(W/C) ids) and ``dma_depth`` in-flight C-word
-    chunk buffers — mirroring ``bitmap_refine``'s hier scratch shapes."""
+    adjacency stays in HBM and its chunk ids and words pass through
+    SMEM, so VMEM holds only the candidate, mask and output row blocks
+    (``HIER_ROWS`` rows of w_pad words, two pipeline buffers each)."""
     w_pad = max(128, ((shape.w + 127) // 128) * 128)
-    n_chunks = (shape.w + chunk_words - 1) // chunk_words
-    rows = 3 * w_pad * 4                 # cand + mask + out row
-    ids = n_chunks * 4                   # chunk-id window (kmax ceiling)
-    bufs = dma_depth * chunk_words * 4   # in-flight chunk slots
-    return rows + ids + bufs
+    return 3 * 2 * HIER_ROWS * w_pad * 4
 
 
 class TunableSpace:
@@ -189,10 +187,9 @@ class TunableSpace:
                     f"wave_size={cfg.wave_size} (a full wave of fresh "
                     "roots must fit one stack bank)")
         if cfg.hbm_adjacency:
-            need = refine_hier_vmem_bytes(self.shape, cfg.chunk_words,
-                                          cfg.dma_depth)
+            need = refine_hier_vmem_bytes(self.shape)
             if need > self.vmem_budget_bytes:
-                return (f"hier refine scratch {need} B exceeds the VMEM "
+                return (f"hier refine row blocks {need} B exceed the VMEM "
                         f"budget {self.vmem_budget_bytes} B at "
                         f"V={self.shape.v}")
             return None

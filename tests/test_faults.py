@@ -102,6 +102,42 @@ def test_retry_exhaustion_demotes_to_host(workload):
     assert r.stats.fallback
 
 
+@pytest.mark.parametrize("failure", ["kernel_lowering", "xla_compile"])
+def test_compile_failure_stops_the_run(workload, monkeypatch, failure):
+    """A device program that fails to compile is not a runtime fault:
+    its error propagates out of the first dispatch — never retried,
+    quarantined or replayed on the host single-step path, which would
+    answer correctly while hiding a device that cannot run the
+    program."""
+    import jax
+    from repro.core import vectorized
+    from repro.kernels.config import backend_scope
+    data, queries, _ = workload
+    calls = []
+    if failure == "xla_compile":
+        # what XLA raises when the TPU compiler refuses a program
+        def refuse(*args, **kwargs):
+            calls.append(1)
+            raise jax.errors.JaxRuntimeError(
+                "RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. "
+                "Ran out of memory in memory space vmem.")
+        monkeypatch.setattr(vectorized, "run_device_megastep", refuse)
+        backend, expected = "jnp", jax.errors.JaxRuntimeError
+    else:
+        # the compiled Pallas kernel cannot lower for the CPU backend
+        backend, expected = "pallas", ValueError
+    with backend_scope(backend):
+        s = MatchSession(data, wave_size=64, n_slots=4)
+        h = s.submit(queries[0], limit=None)
+        with pytest.raises(expected):
+            h.result()
+    f = s.scheduler.scheduler_stats()["faults"]
+    assert f["dispatch_retries"] == 0
+    assert f["quarantined"] == f["fallbacks"] == f["errors"] == 0
+    assert not any(r.host_only for r in s.scheduler.queue)
+    assert len(calls) == (failure == "xla_compile")
+
+
 def test_hang_fires_watchdog_then_fallback(workload):
     """A hung dispatch retires through the watchdog instead of blocking
     the pipeline; the affected query completes via fallback."""

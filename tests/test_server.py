@@ -364,3 +364,104 @@ def test_e2e_slo_and_metrics_shape(served):
     assert set(m["tenants"]) >= {"alpha", "beta"}
     for t in m["tenants"].values():
         assert t["offered"] >= t["admitted"] >= 0
+
+
+# ======================================================================
+# one process per chip, and failures that must stop the server
+# ======================================================================
+def _subprocess_env(**extra) -> dict:
+    import os
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(ROOT / "src") + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env.update(extra)
+    return env
+
+
+def test_client_import_starts_no_jax_backend():
+    """A load generator imports the client and must not hold the chip
+    its server process needs: importing the client, the protocol and
+    the package creates no JAX backend (checked in a fresh
+    interpreter)."""
+    code = ("import repro.server, repro.server.client, "
+            "repro.server.protocol\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n"
+            "import repro.core.vectorized\n"
+            "assert not xla_bridge.backends_are_initialized()\n"
+            "print('no-backend')\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                         env=_subprocess_env(), capture_output=True,
+                         text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "no-backend"
+
+
+def test_launch_exits_nonzero_when_a_kernel_fails_to_compile():
+    """The compiled Pallas backend cannot lower for the CPU: the
+    server's warmup raises, the process exits non-zero and never
+    announces readiness."""
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.server.launch", *SERVER_ARGS],
+        cwd=ROOT, env=_subprocess_env(REPRO_KERNEL_BACKEND="pallas"),
+        capture_output=True, text=True, timeout=600)
+    assert out.returncode != 0
+    assert "REPRO_SERVER_READY" not in out.stdout
+    assert "Only interpret mode" in out.stderr
+
+
+def _small_server(**knobs):
+    from repro.server import MatchServer, ServerArgs
+    data = ba_labeled_graph(GRAPH["n"], GRAPH["m"], GRAPH["labels"],
+                            extra_edges=GRAPH["extra"],
+                            seed=GRAPH["seed"])
+    args = ServerArgs(port=0, n_slots=2, wave_size=32, kpr=8, **knobs)
+    return data, MatchServer(data, args)
+
+
+def test_warmup_raises_when_a_fault_counter_moves():
+    """A warmup the engine only finished by retrying or falling back
+    to the host is a failed warmup: the server must not go ready."""
+    from repro.core.faults import FaultPlan, FaultSpec
+    _, srv = _small_server(warmup_queries=2)
+    try:
+        srv.qserver.scheduler._faults = FaultPlan(
+            [FaultSpec("dispatch", "exception", at=1)])
+        with pytest.raises(RuntimeError, match="warmup failed"):
+            srv.warmup()
+    finally:
+        srv.httpd.server_close()
+
+
+def test_engine_failure_ends_requests_and_serve_forever_raises():
+    """An engine step that raises while serving (a program that fails
+    to compile for a new shape) ends the live request with status
+    ``error``, stops the listener and makes serve_forever raise, so
+    ``repro.server.launch`` exits non-zero."""
+    data, srv = _small_server(warmup_queries=0)
+
+    def broken_step():
+        raise RuntimeError("device program failed to compile")
+
+    srv.qserver.session.step = broken_step
+    raised: list[BaseException] = []
+
+    def serve():
+        try:
+            srv.serve_forever()
+        except RuntimeError as e:
+            raised.append(e)
+
+    t = threading.Thread(target=serve, daemon=True)
+    t.start()
+    try:
+        q = query_set(data, 4, 1, seed=21)[0]
+        _, res = ServeClient(srv.host, srv.port, timeout=60).match(q)
+        assert res["status"] == "error"
+        assert "failed to compile" in res["error"]
+        t.join(timeout=60)
+        assert not t.is_alive()
+        assert raised and "failed to compile" in str(raised[0].__cause__)
+    finally:
+        srv.shutdown(drain=False)
+        srv.httpd.server_close()

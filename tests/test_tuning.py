@@ -162,6 +162,35 @@ def _seed_cache(monkeypatch, tmp_path, n_vertices=512, backend="jnp",
     return params
 
 
+def test_pallas_interpret_refused_off_cpu(monkeypatch):
+    """Interpret mode runs kernels in the host interpreter: resolving it
+    on an accelerator is an error, not a silent slow path."""
+    import jax
+    assert kconfig.resolve("pallas_interpret") == "pallas_interpret"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="pallas_interpret"):
+        kconfig.resolve("pallas_interpret")
+    with kconfig.backend_scope("pallas_interpret"), \
+            pytest.raises(ValueError, match="use 'pallas'"):
+        kconfig.resolve(None)
+    assert kconfig.resolve("pallas") == "pallas"
+    assert kconfig.resolve("jnp") == "jnp"
+
+
+def test_device_kind_never_guesses(monkeypatch):
+    """A device JAX cannot read raises instead of keying tuning records
+    under a made-up kind."""
+    import jax
+
+    def unreadable():
+        raise RuntimeError("no backend")
+
+    assert device_kind() == "cpu"
+    monkeypatch.setattr(jax, "devices", unreadable)
+    with pytest.raises(RuntimeError, match="no backend"):
+        device_kind()
+
+
 def test_resolution_cache_fills_only_unset_knobs(monkeypatch, tmp_path):
     params = _seed_cache(monkeypatch, tmp_path, megastep_depth=4,
                          wave_size=128, block_f=16)
