@@ -129,6 +129,9 @@ class MatchHandle:
         self._worker = None        # sequential stream() worker thread
         # typed failure attached by the session when status == "error"
         self.error: MatchError | None = None
+        # host clock when the query first took an engine slot (None
+        # while it waits in the queue, and for queries that never do)
+        self.t_admit: float | None = None
 
     # ------------------------------------------------------------------
     def done(self) -> bool:
@@ -182,6 +185,12 @@ class MatchHandle:
     def _push(self, batch: np.ndarray) -> None:
         """Embedding-delivery sink (called by the scheduler mid-wave)."""
         self._batches.append(np.asarray(batch, np.int32))
+
+    def _admitted(self) -> None:
+        """Admission sink (called by the scheduler as the query takes
+        a slot; a fallback replay takes one again)."""
+        if self.t_admit is None:
+            self.t_admit = time.perf_counter()
 
     def _complete(self, result: QueryResult) -> None:
         self._result = result

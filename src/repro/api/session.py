@@ -94,7 +94,7 @@ class MatchSession:
         if self.backend == "engine":
             sched_qid = self.scheduler.submit(
                 query, options=opts, cand=cand, order=order,
-                on_embeddings=h._push)
+                on_embeddings=h._push, on_admit=h._admitted)
             h._sched_qid = sched_qid
             h.query_id = sched_qid if query_id is None else query_id
             self._handles[sched_qid] = h

@@ -45,6 +45,7 @@ from jax import lax
 
 from ..patterns.store import (MASK_WORDS, PatternStore, PatternStoreBank,
                               StoreCounters, hash_insert, hash_probe)
+from . import spans
 
 N_PAD = 64              # padded query size
 FULL = np.uint32(0xFFFFFFFF)   # host constant: import starts no backend
@@ -1229,59 +1230,60 @@ def run_device_megastep(g: GraphArrays, qb: QueryBank,
     # Inputs are grouped by slot. Acceptance is throttled so a dispatch
     # leaves allocation headroom; unaccepted roots stay queued on the
     # host (the cursor only advances by d_accepted).
-    isfree = lanes["state"] == STK_FREE
-    free_n = isfree.sum(axis=1).astype(jnp.int32)
-    n_in = _slot_counts(in_slot, in_valid, n_slots)
-    accept_s = jnp.where(active, jnp.minimum(n_in, free_n // (kpr + 2)), 0)
-    rank_in = _group_rank(in_slot, in_valid, n_slots)
-    acc = in_valid & (rank_in < accept_s[in_slot])
+    with jax.named_scope(spans.MEGA_ROOTS):
+        isfree = lanes["state"] == STK_FREE
+        free_n = isfree.sum(axis=1).astype(jnp.int32)
+        n_in = _slot_counts(in_slot, in_valid, n_slots)
+        accept_s = jnp.where(active, jnp.minimum(n_in, free_n // (kpr + 2)), 0)
+        rank_in = _group_rank(in_slot, in_valid, n_slots)
+        acc = in_valid & (rank_in < accept_s[in_slot])
 
-    eor = _free_entry_order(isfree)
-    ent_in = eor[in_slot, rank_in.clip(0, d_cap - 1)]
-    ok_in = acc & (ent_in < d_cap)
-    tgt_s = jnp.where(ok_in, in_slot, n_slots)
-    tgt_e = jnp.where(ok_in, ent_in, 0)
+        eor = _free_entry_order(isfree)
+        ent_in = eor[in_slot, rank_in.clip(0, d_cap - 1)]
+        ok_in = acc & (ent_in < d_cap)
+        tgt_s = jnp.where(ok_in, in_slot, n_slots)
+        tgt_e = jnp.where(ok_in, ent_in, 0)
 
-    root_f = jnp.where(jnp.arange(N_PAD)[None, :] == 0,
-                       in_root[:, None], -1).astype(jnp.int32)
-    rv = in_root.clip(0)
-    root_u = jnp.zeros((r, w), jnp.uint32).at[
-        jnp.arange(r), (rv // 32)].set(jnp.uint32(1) << (rv % 32).astype(
-            jnp.uint32))
-    root_p = jnp.where(jnp.arange(N_PAD + 1)[None, :] == 1,
-                       in_rid[:, None], 0).astype(jnp.int32)
+        root_f = jnp.where(jnp.arange(N_PAD)[None, :] == 0,
+                           in_root[:, None], -1).astype(jnp.int32)
+        rv = in_root.clip(0)
+        root_u = jnp.zeros((r, w), jnp.uint32).at[
+            jnp.arange(r), (rv // 32)].set(jnp.uint32(1) << (rv % 32).astype(
+                jnp.uint32))
+        root_p = jnp.where(jnp.arange(N_PAD + 1)[None, :] == 1,
+                           in_rid[:, None], 0).astype(jnp.int32)
 
-    lanes["frontier"] = lanes["frontier"].at[tgt_s, tgt_e].set(
-        root_f, mode="drop")
-    lanes["used"] = lanes["used"].at[tgt_s, tgt_e].set(root_u, mode="drop")
-    lanes["phi"] = lanes["phi"].at[tgt_s, tgt_e].set(root_p, mode="drop")
-    lanes["depth"] = lanes["depth"].at[tgt_s, tgt_e].set(1, mode="drop")
-    lanes["state"] = lanes["state"].at[tgt_s, tgt_e].set(
-        jnp.int8(STK_FRESH), mode="drop")
-    lanes["gamma"] = lanes["gamma"].at[tgt_s, tgt_e].set(
-        jnp.uint32(0), mode="drop")
-    lanes["outstanding"] = lanes["outstanding"].at[tgt_s, tgt_e].set(
-        0, mode="drop")
-    lanes["reported"] = lanes["reported"].at[tgt_s, tgt_e].set(
-        False, mode="drop")
-    lanes["parent"] = lanes["parent"].at[tgt_s, tgt_e].set(-1, mode="drop")
-    lanes["cand"] = lanes["cand"].at[tgt_s, tgt_e].set(
-        jnp.uint32(0), mode="drop")
-    push_pos = jnp.where(ok_in, lanes["ptop"][in_slot] + rank_in, 0)
-    lanes["pstack"] = lanes["pstack"].at[tgt_s, push_pos].set(
-        ent_in, mode="drop")
-    d_accepted = _slot_counts(in_slot, ok_in, n_slots)
-    lanes["ptop"] = lanes["ptop"] + d_accepted
+        lanes["frontier"] = lanes["frontier"].at[tgt_s, tgt_e].set(
+            root_f, mode="drop")
+        lanes["used"] = lanes["used"].at[tgt_s, tgt_e].set(root_u, mode="drop")
+        lanes["phi"] = lanes["phi"].at[tgt_s, tgt_e].set(root_p, mode="drop")
+        lanes["depth"] = lanes["depth"].at[tgt_s, tgt_e].set(1, mode="drop")
+        lanes["state"] = lanes["state"].at[tgt_s, tgt_e].set(
+            jnp.int8(STK_FRESH), mode="drop")
+        lanes["gamma"] = lanes["gamma"].at[tgt_s, tgt_e].set(
+            jnp.uint32(0), mode="drop")
+        lanes["outstanding"] = lanes["outstanding"].at[tgt_s, tgt_e].set(
+            0, mode="drop")
+        lanes["reported"] = lanes["reported"].at[tgt_s, tgt_e].set(
+            False, mode="drop")
+        lanes["parent"] = lanes["parent"].at[tgt_s, tgt_e].set(-1, mode="drop")
+        lanes["cand"] = lanes["cand"].at[tgt_s, tgt_e].set(
+            jnp.uint32(0), mode="drop")
+        push_pos = jnp.where(ok_in, lanes["ptop"][in_slot] + rank_in, 0)
+        lanes["pstack"] = lanes["pstack"].at[tgt_s, push_pos].set(
+            ent_in, mode="drop")
+        d_accepted = _slot_counts(in_slot, ok_in, n_slots)
+        lanes["ptop"] = lanes["ptop"] + d_accepted
 
-    zs = jnp.zeros((n_slots,), jnp.int32)
-    carry = dict(
-        tb=tb, it=jnp.int32(0),
-        emb_frontier=jnp.full((emb_cap, N_PAD), -1, jnp.int32),
-        emb_slot=jnp.zeros((emb_cap,), jnp.int32), n_emb=jnp.int32(0),
-        id_ctr=jnp.asarray(id_base, jnp.int32),
-        pat=StoreCounters.zeros(n_slots),
-        d_expanded=zs, d_rows=zs, d_prunes=zs, d_inj=zs, d_stored=zs,
-        **lanes)
+        zs = jnp.zeros((n_slots,), jnp.int32)
+        carry = dict(
+            tb=tb, it=jnp.int32(0),
+            emb_frontier=jnp.full((emb_cap, N_PAD), -1, jnp.int32),
+            emb_slot=jnp.zeros((emb_cap,), jnp.int32), n_emb=jnp.int32(0),
+            id_ctr=jnp.asarray(id_base, jnp.int32),
+            pat=StoreCounters.zeros(n_slots),
+            d_expanded=zs, d_rows=zs, d_prunes=zs, d_inj=zs, d_stored=zs,
+            **lanes)
 
     lane_keys = tuple(lanes.keys())
 
@@ -1294,208 +1296,218 @@ def run_device_megastep(g: GraphArrays, qb: QueryBank,
         st, ptop = s["state"], s["ptop"]
 
         # ---- wave selection: waterfill quota over pending slots --------
-        pend = jnp.where(active, ptop, 0)
-        free_now = (st == STK_FREE).sum(axis=1).astype(jnp.int32)
-        quota_cap = jnp.maximum(free_now // (kpr + 1), 1)
-        desire = jnp.minimum(pend, quota_cap)
-        n_act = jnp.maximum((desire > 0).sum(), 1)
-        base = jnp.int32(f) // n_act
-        q1 = jnp.minimum(desire, base)
-        want = desire - q1
-        rem = jnp.int32(f) - q1.sum()
-        extra = jnp.clip(jnp.minimum(
-            want, rem - (jnp.cumsum(want) - want)), 0, None)
-        q = q1 + extra                                       # [S]
-        offs = jnp.cumsum(q) - q
-        total = q.sum()
-        s_of = jnp.searchsorted(jnp.cumsum(q), f_rows,
-                                side="right").astype(jnp.int32)
-        row_valid = f_rows < total
-        s_of_c = jnp.where(row_valid, s_of, 0).clip(0, n_slots - 1)
-        k_in = (f_rows - offs[s_of_c]).clip(0)
-        ent_sel = s["pstack"][s_of_c, (ptop[s_of_c] - 1 - k_in).clip(0)]
-        e_c = jnp.where(row_valid, ent_sel, 0)
-        ptop2 = ptop - q
+        with jax.named_scope(spans.MEGA_SELECT):
+            pend = jnp.where(active, ptop, 0)
+            free_now = (st == STK_FREE).sum(axis=1).astype(jnp.int32)
+            quota_cap = jnp.maximum(free_now // (kpr + 1), 1)
+            desire = jnp.minimum(pend, quota_cap)
+            n_act = jnp.maximum((desire > 0).sum(), 1)
+            base = jnp.int32(f) // n_act
+            q1 = jnp.minimum(desire, base)
+            want = desire - q1
+            rem = jnp.int32(f) - q1.sum()
+            extra = jnp.clip(jnp.minimum(
+                want, rem - (jnp.cumsum(want) - want)), 0, None)
+            q = q1 + extra                                       # [S]
+            offs = jnp.cumsum(q) - q
+            total = q.sum()
+            s_of = jnp.searchsorted(jnp.cumsum(q), f_rows,
+                                    side="right").astype(jnp.int32)
+            row_valid = f_rows < total
+            s_of_c = jnp.where(row_valid, s_of, 0).clip(0, n_slots - 1)
+            k_in = (f_rows - offs[s_of_c]).clip(0)
+            ent_sel = s["pstack"][s_of_c, (ptop[s_of_c] - 1 - k_in).clip(0)]
+            e_c = jnp.where(row_valid, ent_sel, 0)
+            ptop2 = ptop - q
 
-        wf = s["frontier"][s_of_c, e_c]
-        wu = s["used"][s_of_c, e_c]
-        wphi = s["phi"][s_of_c, e_c]
-        wd = s["depth"][s_of_c, e_c]
-        wcand = s["cand"][s_of_c, e_c]
-        wg = s["gamma"][s_of_c, e_c]
-        st_sel = st[s_of_c, e_c]
-        is_left = (st_sel == STK_LEFT) & row_valid
-        is_fresh = (st_sel == STK_FRESH) & row_valid
+            wf = s["frontier"][s_of_c, e_c]
+            wu = s["used"][s_of_c, e_c]
+            wphi = s["phi"][s_of_c, e_c]
+            wd = s["depth"][s_of_c, e_c]
+            wcand = s["cand"][s_of_c, e_c]
+            wg = s["gamma"][s_of_c, e_c]
+            st_sel = st[s_of_c, e_c]
+            is_left = (st_sel == STK_LEFT) & row_valid
+            is_fresh = (st_sel == STK_FRESH) & row_valid
 
         # ---- expansion (fresh: full Eq.2 pass; LEFT: re-extraction) ----
-        refined = refine_eq2_mq(g, qb, s_of_c, wf, wd, backend, block_f,
-                                dma_depth)
-        refined = jnp.where(is_fresh[:, None], refined, jnp.uint32(0))
-        refined_empty = is_fresh & (_popcount_rows(refined) == 0)
+        with jax.named_scope(spans.MEGA_REFINE):
+            refined = refine_eq2_mq(g, qb, s_of_c, wf, wd, backend, block_f,
+                                    dma_depth)
+            refined = jnp.where(is_fresh[:, None], refined, jnp.uint32(0))
+            refined_empty = is_fresh & (_popcount_rows(refined) == 0)
 
-        inj_words = refined & wu
-        n_inj_row = jnp.where(is_fresh, _popcount_rows(inj_words), 0)
-        depth_bits = _position_bits(wd)
+        with jax.named_scope(spans.MEGA_INJECT):
+            inj_words = refined & wu
+            n_inj_row = jnp.where(is_fresh, _popcount_rows(inj_words), 0)
+            depth_bits = _position_bits(wd)
 
-        # vectorized over positions (no fori_loop): position bits are
-        # disjoint across p, so the OR-fold is an exact integer sum
-        verts = wf.clip(0)                                   # [F, NP]
-        words = jnp.take_along_axis(refined, verts // 32, axis=1)
-        hit = ((words >> (verts % 32).astype(jnp.uint32)) & 1) > 0
-        hit &= (jnp.arange(N_PAD)[None, :] < wd[:, None]) \
-            & is_fresh[:, None]
-        posb = _position_bits(jnp.arange(N_PAD, dtype=jnp.int32))
-        inj_mask = (hit[:, :, None].astype(jnp.uint32)
-                    * posb[None, :, :]).sum(axis=1, dtype=jnp.uint32)
-        inj_mask = inj_mask | jnp.where(hit.any(axis=1)[:, None],
-                                        depth_bits, jnp.uint32(0))
+            # vectorized over positions (no fori_loop): position bits are
+            # disjoint across p, so the OR-fold is an exact integer sum
+            verts = wf.clip(0)                                   # [F, NP]
+            words = jnp.take_along_axis(refined, verts // 32, axis=1)
+            hit = ((words >> (verts % 32).astype(jnp.uint32)) & 1) > 0
+            hit &= (jnp.arange(N_PAD)[None, :] < wd[:, None]) \
+                & is_fresh[:, None]
+            posb = _position_bits(jnp.arange(N_PAD, dtype=jnp.int32))
+            inj_mask = (hit[:, :, None].astype(jnp.uint32)
+                        * posb[None, :, :]).sum(axis=1, dtype=jnp.uint32)
+            inj_mask = inj_mask | jnp.where(hit.any(axis=1)[:, None],
+                                            depth_bits, jnp.uint32(0))
 
-        live = jnp.where(is_left[:, None], wcand, refined & ~wu)
-        child_v, leftover, n_leftover = _extract_topk_packed(live, kpr)
-        prune, prune_mask, tb_l = deadend_lookup_children_mq(
-            s["tb"], wphi, s_of_c, wd, child_v)
-        child_valid = (child_v >= 0) & ~prune & row_valid[:, None]
-        partial = jnp.where(is_left[:, None], prune_mask,
-                            inj_mask | prune_mask)
-        n_pruned_row = jnp.where(row_valid, prune.sum(axis=1), 0)
+        with jax.named_scope(spans.MEGA_EXTRACT):
+            live = jnp.where(is_left[:, None], wcand, refined & ~wu)
+            child_v, leftover, n_leftover = _extract_topk_packed(live, kpr)
+        with jax.named_scope(spans.MEGA_PROBE):
+            prune, prune_mask, tb_l = deadend_lookup_children_mq(
+                s["tb"], wphi, s_of_c, wd, child_v)
+            child_valid = (child_v >= 0) & ~prune & row_valid[:, None]
+            partial = jnp.where(is_left[:, None], prune_mask,
+                                inj_mask | prune_mask)
+            n_pruned_row = jnp.where(row_valid, prune.sum(axis=1), 0)
 
-        # ---- materialize children (flat [F*kpr], slot-grouped) ---------
-        parent_local = jnp.repeat(jnp.arange(f, dtype=jnp.int32), kpr)
-        flat_v = child_v.reshape(-1)
-        cvalid_flat = child_valid.reshape(-1)
-        d_par = wd[parent_local]
-        slot_flat = s_of_c[parent_local]
-        is_last = wd + 1 == qb.n_query[s_of_c]
-        last_flat = is_last[parent_local]
-        pos = jnp.arange(N_PAD)
-        cf2 = wf[parent_local]
-        cf2 = jnp.where((pos[None, :] == d_par[:, None])
-                        & cvalid_flat[:, None], flat_v[:, None], cf2)
-        vv = flat_v.clip(0)
+        with jax.named_scope(spans.MEGA_EXTRACT):
+            # ---- materialize children (flat [F*kpr], slot-grouped) ---------
+            parent_local = jnp.repeat(jnp.arange(f, dtype=jnp.int32), kpr)
+            flat_v = child_v.reshape(-1)
+            cvalid_flat = child_valid.reshape(-1)
+            d_par = wd[parent_local]
+            slot_flat = s_of_c[parent_local]
+            is_last = wd + 1 == qb.n_query[s_of_c]
+            last_flat = is_last[parent_local]
+            pos = jnp.arange(N_PAD)
+            cf2 = wf[parent_local]
+            cf2 = jnp.where((pos[None, :] == d_par[:, None])
+                            & cvalid_flat[:, None], flat_v[:, None], cf2)
+            vv = flat_v.clip(0)
 
-        # ---- embeddings: last-level children, no allocation ------------
-        emb_valid = cvalid_flat & last_flat
-        emb_off = jnp.cumsum(emb_valid.astype(jnp.int32)) - 1
-        emb_idx = jnp.where(emb_valid, s["n_emb"] + emb_off, emb_cap)
-        emb_frontier = s["emb_frontier"].at[emb_idx].set(cf2, mode="drop")
-        emb_slot = s["emb_slot"].at[emb_idx].set(slot_flat, mode="drop")
-        n_emb_new = emb_valid.sum().astype(jnp.int32)
-        n_emb_row = (child_valid & is_last[:, None]).sum(
-            axis=1).astype(jnp.int32)
+            # ---- embeddings: last-level children, no allocation ------------
+            emb_valid = cvalid_flat & last_flat
+            emb_off = jnp.cumsum(emb_valid.astype(jnp.int32)) - 1
+            emb_idx = jnp.where(emb_valid, s["n_emb"] + emb_off, emb_cap)
+            emb_frontier = s["emb_frontier"].at[emb_idx].set(cf2, mode="drop")
+            emb_slot = s["emb_slot"].at[emb_idx].set(slot_flat, mode="drop")
+            n_emb_new = emb_valid.sum().astype(jnp.int32)
+            n_emb_row = (child_valid & is_last[:, None]).sum(
+                axis=1).astype(jnp.int32)
 
-        # ---- allocate non-last children into free entries --------------
-        # compacted to at most ``a_cap`` rows: the CPU backend executes
-        # scatter updates serially, so every lane scatter below costs
-        # per-row — and most of the F·kpr child rows are dead padding.
-        # Children past the cap simply fold back into their parent's
-        # leftover bitmap (LEFT requeue), the same sound degradation as
-        # running out of free entries.
-        eor_l = _free_entry_order(st == STK_FREE)
-        app_valid = cvalid_flat & ~last_flat
-        a_sel = _select_set_bits(app_valid, a_cap)           # [A]
-        a_valid = a_sel < f * kpr
-        a_i = a_sel.clip(0, f * kpr - 1)
-        slot_a = slot_flat[a_i]
-        par_a = parent_local[a_i]
-        j = _group_rank(slot_a, a_valid, n_slots)
-        ent_ch = eor_l[slot_a, j.clip(0, d_cap - 1)]
-        ok = a_valid & (ent_ch < d_cap)
-        alloc_flag = jnp.zeros((f * kpr,), bool).at[
-            jnp.where(ok, a_sel, f * kpr)].set(True, mode="drop")
-        fail = app_valid & ~alloc_flag
+        with jax.named_scope(spans.MEGA_ALLOC):
+            # ---- allocate non-last children into free entries --------------
+            # compacted to at most ``a_cap`` rows: the CPU backend executes
+            # scatter updates serially, so every lane scatter below costs
+            # per-row — and most of the F·kpr child rows are dead padding.
+            # Children past the cap simply fold back into their parent's
+            # leftover bitmap (LEFT requeue), the same sound degradation as
+            # running out of free entries.
+            eor_l = _free_entry_order(st == STK_FREE)
+            app_valid = cvalid_flat & ~last_flat
+            a_sel = _select_set_bits(app_valid, a_cap)           # [A]
+            a_valid = a_sel < f * kpr
+            a_i = a_sel.clip(0, f * kpr - 1)
+            slot_a = slot_flat[a_i]
+            par_a = parent_local[a_i]
+            j = _group_rank(slot_a, a_valid, n_slots)
+            ent_ch = eor_l[slot_a, j.clip(0, d_cap - 1)]
+            ok = a_valid & (ent_ch < d_cap)
+            alloc_flag = jnp.zeros((f * kpr,), bool).at[
+                jnp.where(ok, a_sel, f * kpr)].set(True, mode="drop")
+            fail = app_valid & ~alloc_flag
 
-        # children that found no entry fold back into the parent row's
-        # leftover bitmap (distinct vertices → add == or)
-        fold = jnp.zeros((f, w), jnp.uint32).at[
-            parent_local, (vv // 32)].add(
-                jnp.where(fail,
-                          jnp.uint32(1) << (vv % 32).astype(jnp.uint32),
-                          jnp.uint32(0)))
-        leftover = leftover | fold
-        n_leftover = _popcount_rows(leftover)
+            # children that found no entry fold back into the parent row's
+            # leftover bitmap (distinct vertices → add == or)
+            fold = jnp.zeros((f, w), jnp.uint32).at[
+                parent_local, (vv // 32)].add(
+                    jnp.where(fail,
+                              jnp.uint32(1) << (vv % 32).astype(jnp.uint32),
+                              jnp.uint32(0)))
+            leftover = leftover | fold
+            n_leftover = _popcount_rows(leftover)
 
-        ok_s = jnp.where(ok, slot_a, n_slots)
-        ok_e = jnp.where(ok, ent_ch, 0)
-        child_ids = s["id_ctr"] + jnp.cumsum(ok.astype(jnp.int32)) - 1
-        d_par_a = d_par[a_i]
-        vv_a = vv[a_i]
-        cf_a = cf2[a_i]
-        cu_a = wu[par_a] | jnp.zeros((a_cap, w), jnp.uint32).at[
-            jnp.arange(a_cap), (vv_a // 32)].set(
-                jnp.uint32(1) << (vv_a % 32).astype(jnp.uint32))
-        pos_phi = jnp.arange(N_PAD + 1)
-        cp_a = wphi[par_a]
-        cp_a = jnp.where((pos_phi[None, :] == d_par_a[:, None] + 1)
-                         & ok[:, None], child_ids[:, None], cp_a)
-        n_alloc = ok.sum().astype(jnp.int32)
-        n_alloc_row = alloc_flag.reshape(f, kpr).sum(
-            axis=1).astype(jnp.int32)
-        alloc_s = _slot_counts(slot_a, ok, n_slots)
+            ok_s = jnp.where(ok, slot_a, n_slots)
+            ok_e = jnp.where(ok, ent_ch, 0)
+            child_ids = s["id_ctr"] + jnp.cumsum(ok.astype(jnp.int32)) - 1
+            d_par_a = d_par[a_i]
+            vv_a = vv[a_i]
+            cf_a = cf2[a_i]
+            cu_a = wu[par_a] | jnp.zeros((a_cap, w), jnp.uint32).at[
+                jnp.arange(a_cap), (vv_a // 32)].set(
+                    jnp.uint32(1) << (vv_a % 32).astype(jnp.uint32))
+            pos_phi = jnp.arange(N_PAD + 1)
+            cp_a = wphi[par_a]
+            cp_a = jnp.where((pos_phi[None, :] == d_par_a[:, None] + 1)
+                             & ok[:, None], child_ids[:, None], cp_a)
+            n_alloc = ok.sum().astype(jnp.int32)
+            n_alloc_row = alloc_flag.reshape(f, kpr).sum(
+                axis=1).astype(jnp.int32)
+            alloc_s = _slot_counts(slot_a, ok, n_slots)
 
-        fr_l = s["frontier"].at[ok_s, ok_e].set(cf_a, mode="drop")
-        us_l = s["used"].at[ok_s, ok_e].set(cu_a, mode="drop")
-        ph_l = s["phi"].at[ok_s, ok_e].set(cp_a, mode="drop")
-        de_l = s["depth"].at[ok_s, ok_e].set(d_par_a + 1, mode="drop")
-        st_l = st.at[ok_s, ok_e].set(jnp.int8(STK_FRESH), mode="drop")
-        gm_l = s["gamma"].at[ok_s, ok_e].set(jnp.uint32(0), mode="drop")
-        ou_l = s["outstanding"].at[ok_s, ok_e].set(0, mode="drop")
-        re_l = s["reported"].at[ok_s, ok_e].set(False, mode="drop")
-        pa_l = s["parent"].at[ok_s, ok_e].set(
-            ent_sel[par_a], mode="drop")
-        ca_l = s["cand"].at[ok_s, ok_e].set(jnp.uint32(0), mode="drop")
+            fr_l = s["frontier"].at[ok_s, ok_e].set(cf_a, mode="drop")
+            us_l = s["used"].at[ok_s, ok_e].set(cu_a, mode="drop")
+            ph_l = s["phi"].at[ok_s, ok_e].set(cp_a, mode="drop")
+            de_l = s["depth"].at[ok_s, ok_e].set(d_par_a + 1, mode="drop")
+            st_l = st.at[ok_s, ok_e].set(jnp.int8(STK_FRESH), mode="drop")
+            gm_l = s["gamma"].at[ok_s, ok_e].set(jnp.uint32(0), mode="drop")
+            ou_l = s["outstanding"].at[ok_s, ok_e].set(0, mode="drop")
+            re_l = s["reported"].at[ok_s, ok_e].set(False, mode="drop")
+            pa_l = s["parent"].at[ok_s, ok_e].set(
+                ent_sel[par_a], mode="drop")
+            ca_l = s["cand"].at[ok_s, ok_e].set(jnp.uint32(0), mode="drop")
 
-        # ---- in-loop Lemma-1 stores (Eq. 2 came back empty) ------------
-        do_store = (refined_empty & (wd >= 1)
-                    & qb.learn[s_of_c] & learn_enabled)
-        qnbr = _pack_mask_rows(qb.nbr_mask[s_of_c, wd])
-        gamma_w = qnbr & _below_bits_rows(wd)
-        key_pos = (wd - 1).clip(0)
-        key_v = jnp.take_along_axis(wf, key_pos[:, None], axis=1)[:, 0]
-        mu = _mask_bitlen(gamma_w & _below_bits_rows(key_pos))
-        phi_id = jnp.take_along_axis(wphi, mu[:, None], axis=1)[:, 0]
-        tb2, pat_c = store_patterns_mq(tb_l, s_of_c, key_pos, key_v,
-                                       phi_id, mu, gamma_w, do_store)
+        with jax.named_scope(spans.MEGA_STORE):
+            # ---- in-loop Lemma-1 stores (Eq. 2 came back empty) ------------
+            do_store = (refined_empty & (wd >= 1)
+                        & qb.learn[s_of_c] & learn_enabled)
+            qnbr = _pack_mask_rows(qb.nbr_mask[s_of_c, wd])
+            gamma_w = qnbr & _below_bits_rows(wd)
+            key_pos = (wd - 1).clip(0)
+            key_v = jnp.take_along_axis(wf, key_pos[:, None], axis=1)[:, 0]
+            mu = _mask_bitlen(gamma_w & _below_bits_rows(key_pos))
+            phi_id = jnp.take_along_axis(wphi, mu[:, None], axis=1)[:, 0]
+            tb2, pat_c = store_patterns_mq(tb_l, s_of_c, key_pos, key_v,
+                                           phi_id, mu, gamma_w, do_store)
 
-        # ---- update the selected entries -------------------------------
-        has_left = (n_leftover > 0) & row_valid & ~refined_empty
-        new_state = jnp.where(
-            refined_empty, jnp.int8(STK_RES),
-            jnp.where(has_left, jnp.int8(STK_LEFT), jnp.int8(STK_WAIT)))
-        new_g = (wg | partial
-                 | jnp.where(refined_empty[:, None], gamma_w,
-                             jnp.uint32(0)))
-        sel_s = jnp.where(row_valid, s_of_c, n_slots)
-        st_l = st_l.at[sel_s, e_c].set(new_state, mode="drop")
-        gm_l = gm_l.at[sel_s, e_c].set(new_g, mode="drop")
-        ou_l = ou_l.at[sel_s, e_c].set(
-            s["outstanding"][s_of_c, e_c] + n_alloc_row, mode="drop")
-        re_l = re_l.at[sel_s, e_c].set(
-            s["reported"][s_of_c, e_c] | (n_emb_row > 0), mode="drop")
-        ca_l = ca_l.at[sel_s, e_c].set(
-            jnp.where(has_left[:, None], leftover, jnp.uint32(0)),
-            mode="drop")
+        with jax.named_scope(spans.MEGA_ALLOC):
+            # ---- update the selected entries -------------------------------
+            has_left = (n_leftover > 0) & row_valid & ~refined_empty
+            new_state = jnp.where(
+                refined_empty, jnp.int8(STK_RES),
+                jnp.where(has_left, jnp.int8(STK_LEFT), jnp.int8(STK_WAIT)))
+            new_g = (wg | partial
+                     | jnp.where(refined_empty[:, None], gamma_w,
+                                 jnp.uint32(0)))
+            sel_s = jnp.where(row_valid, s_of_c, n_slots)
+            st_l = st_l.at[sel_s, e_c].set(new_state, mode="drop")
+            gm_l = gm_l.at[sel_s, e_c].set(new_g, mode="drop")
+            ou_l = ou_l.at[sel_s, e_c].set(
+                s["outstanding"][s_of_c, e_c] + n_alloc_row, mode="drop")
+            re_l = re_l.at[sel_s, e_c].set(
+                s["reported"][s_of_c, e_c] | (n_emb_row > 0), mode="drop")
+            ca_l = ca_l.at[sel_s, e_c].set(
+                jnp.where(has_left[:, None], leftover, jnp.uint32(0)),
+                mode="drop")
 
-        # ---- re-queue: LEFT entries below, fresh children on top -------
-        lrank = _group_rank(s_of_c, has_left, n_slots)
-        lpos = jnp.where(has_left, ptop2[s_of_c] + lrank, 0)
-        ps_l = s["pstack"].at[
-            jnp.where(has_left, s_of_c, n_slots), lpos].set(
-                ent_sel, mode="drop")
-        n_left_s = _slot_counts(s_of_c, has_left, n_slots)
-        ptop3 = ptop2 + n_left_s
-        cpos = jnp.where(ok, ptop3[slot_a] + j, 0)
-        ps_l = ps_l.at[jnp.where(ok, slot_a, n_slots), cpos].set(
-            ent_ch, mode="drop")
-        ptop4 = ptop3 + alloc_s
+            # ---- re-queue: LEFT entries below, fresh children on top -------
+            lrank = _group_rank(s_of_c, has_left, n_slots)
+            lpos = jnp.where(has_left, ptop2[s_of_c] + lrank, 0)
+            ps_l = s["pstack"].at[
+                jnp.where(has_left, s_of_c, n_slots), lpos].set(
+                    ent_sel, mode="drop")
+            n_left_s = _slot_counts(s_of_c, has_left, n_slots)
+            ptop3 = ptop2 + n_left_s
+            cpos = jnp.where(ok, ptop3[slot_a] + j, 0)
+            ps_l = ps_l.at[jnp.where(ok, slot_a, n_slots), cpos].set(
+                ent_ch, mode="drop")
+            ptop4 = ptop3 + alloc_s
 
-        new_lanes = dict(
-            frontier=fr_l, used=us_l, phi=ph_l, depth=de_l, cand=ca_l,
-            state=st_l, gamma=gm_l, outstanding=ou_l, reported=re_l,
-            parent=pa_l, pstack=ps_l, ptop=ptop4)
+            new_lanes = dict(
+                frontier=fr_l, used=us_l, phi=ph_l, depth=de_l, cand=ca_l,
+                state=st_l, gamma=gm_l, outstanding=ou_l, reported=re_l,
+                parent=pa_l, pstack=ps_l, ptop=ptop4)
 
         # ---- one resolution sweep per iteration ------------------------
-        tb3, new_lanes, n_stored_fin, pat_f = _resolution_sweep(
-            qb, tb2, new_lanes, learn_enabled, f)
+        with jax.named_scope(spans.MEGA_RESOLVE):
+            tb3, new_lanes, n_stored_fin, pat_f = _resolution_sweep(
+                qb, tb2, new_lanes, learn_enabled, f)
 
         return dict(
             tb=tb3, it=s["it"] + 1,
@@ -1513,7 +1525,8 @@ def run_device_megastep(g: GraphArrays, qb: QueryBank,
                 s_of_c, do_store, n_slots),
             **new_lanes)
 
-    s = lax.while_loop(cond, body, carry)
+    with jax.named_scope(spans.MEGA_SELECT):
+        s = lax.while_loop(cond, body, carry)
 
     # ---- final drain: a few more resolution sweeps ---------------------
     # Bounded by a small constant, not run to quiescence: each sweep
@@ -1522,45 +1535,46 @@ def run_device_megastep(g: GraphArrays, qb: QueryBank,
     # in-loop sweeps (or its own drain) continue the fold, and trailing
     # resolution-only dispatches are cheap because the expansion loop
     # exits immediately with nothing pending.
-    def drain_cond(d):
-        can_fold = (d["state"] == STK_RES).any()
-        can_fin = ((d["state"] == STK_WAIT)
-                   & (d["outstanding"] == 0)).any()
-        return (can_fold | can_fin) & (d["it"] < 12)
+    with jax.named_scope(spans.MEGA_DRAIN):
+        def drain_cond(d):
+            can_fold = (d["state"] == STK_RES).any()
+            can_fin = ((d["state"] == STK_WAIT)
+                       & (d["outstanding"] == 0)).any()
+            return (can_fold | can_fin) & (d["it"] < 12)
 
-    def drain_body(d):
-        lanes_d = {k: d[k] for k in lane_keys}
-        tb_d, lanes_d, n_st, pat_d = _resolution_sweep(
-            qb, d["tb"], lanes_d, learn_enabled, f)
-        return dict(d, tb=tb_d, it=d["it"] + 1,
-                    d_stored=d["d_stored"] + n_st,
-                    pat=d["pat"].add(pat_d), **lanes_d)
+        def drain_body(d):
+            lanes_d = {k: d[k] for k in lane_keys}
+            tb_d, lanes_d, n_st, pat_d = _resolution_sweep(
+                qb, d["tb"], lanes_d, learn_enabled, f)
+            return dict(d, tb=tb_d, it=d["it"] + 1,
+                        d_stored=d["d_stored"] + n_st,
+                        pat=d["pat"].add(pat_d), **lanes_d)
 
-    s = lax.while_loop(drain_cond, drain_body,
-                       dict(s, it=jnp.int32(0)))
+        s = lax.while_loop(drain_cond, drain_body,
+                           dict(s, it=jnp.int32(0)))
 
-    sb_out = StackBank(**{k: s[k] for k in lane_keys})
-    live_mask = s["state"] != STK_FREE
-    live = live_mask.sum(axis=1).astype(jnp.int32)
-    # Lemma-4 conservation lanes for the host-side digest validator:
-    # every live non-root entry is counted exactly once in its parent's
-    # outstanding counter, so per slot
-    #   sum(outstanding over live) == count(live with parent >= 0)
-    d_outsum = jnp.where(live_mask, s["outstanding"], 0) \
-        .sum(axis=1).astype(jnp.int32)
-    d_childlive = (live_mask & (s["parent"] >= 0)) \
-        .sum(axis=1).astype(jnp.int32)
-    return DeviceResult(
-        tb=s["tb"], sb=sb_out,
-        d_accepted=d_accepted, d_expanded=s["d_expanded"],
-        d_rows=s["d_rows"], d_prunes=s["d_prunes"], d_inj=s["d_inj"],
-        d_stored=s["d_stored"], d_pending=s["ptop"], d_live=live,
-        d_outsum=d_outsum, d_childlive=d_childlive,
-        pat_stored=s["pat"].stored, pat_overwrites=s["pat"].overwrites,
-        pat_evictions=s["pat"].evictions, pat_dropped=s["pat"].dropped,
-        emb_frontier=s["emb_frontier"], emb_slot=s["emb_slot"],
-        n_emb=s["n_emb"],
-        n_ids=s["id_ctr"] - jnp.asarray(id_base, jnp.int32))
+        sb_out = StackBank(**{k: s[k] for k in lane_keys})
+        live_mask = s["state"] != STK_FREE
+        live = live_mask.sum(axis=1).astype(jnp.int32)
+        # Lemma-4 conservation lanes for the host-side digest validator:
+        # every live non-root entry is counted exactly once in its parent's
+        # outstanding counter, so per slot
+        #   sum(outstanding over live) == count(live with parent >= 0)
+        d_outsum = jnp.where(live_mask, s["outstanding"], 0) \
+            .sum(axis=1).astype(jnp.int32)
+        d_childlive = (live_mask & (s["parent"] >= 0)) \
+            .sum(axis=1).astype(jnp.int32)
+        return DeviceResult(
+            tb=s["tb"], sb=sb_out,
+            d_accepted=d_accepted, d_expanded=s["d_expanded"],
+            d_rows=s["d_rows"], d_prunes=s["d_prunes"], d_inj=s["d_inj"],
+            d_stored=s["d_stored"], d_pending=s["ptop"], d_live=live,
+            d_outsum=d_outsum, d_childlive=d_childlive,
+            pat_stored=s["pat"].stored, pat_overwrites=s["pat"].overwrites,
+            pat_evictions=s["pat"].evictions, pat_dropped=s["pat"].dropped,
+            emb_frontier=s["emb_frontier"], emb_slot=s["emb_slot"],
+            n_emb=s["n_emb"],
+            n_ids=s["id_ctr"] - jnp.asarray(id_base, jnp.int32))
 
 
 # (the old single-query S == 1 wrappers — expand_wave &c. — are gone:

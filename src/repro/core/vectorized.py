@@ -91,7 +91,9 @@ from .engine_step import (MASK_WORDS, N_PAD, STK_FREE, STK_FRESH,
                           extract_more_mq, load_slot, load_slots,
                           read_store_slot, run_device_megastep,
                           run_megastep_mq, store_patterns_mq)
+from . import spans
 from .graph import Graph, pack_bitmap
+from .spans import Acc, span
 from .segments import (EngineStats, QueryState, Segment, SegmentPool,
                        WorkItem, below, bit_of, mask64, words_from64)
 
@@ -136,6 +138,8 @@ class _Request:
     priority: int = 0
     # streamed-embedding sink (MatchHandle._push); None = no streaming
     on_embeddings: object | None = None
+    # called when the query first takes a slot (MatchHandle._admitted)
+    on_admit: object | None = None
     # ---- degraded-mode replay (DESIGN.md §8) --------------------------
     # a quarantined query is re-admitted as a fresh request on the host
     # single-step fallback path, carrying the embeddings it already
@@ -365,16 +369,21 @@ class WaveScheduler:
         # per-slot work accounting (megastep digest lanes + host waves)
         self.slot_rows_expanded = np.zeros(self.n_slots, np.int64)
         self.slot_children_created = np.zeros(self.n_slots, np.int64)
-        # host/device time split (serving_bench trajectory)
-        self.t_dispatch_s = 0.0     # pack + async dispatch (host)
-        self.t_sync_s = 0.0         # blocked materializing digests
-        self.t_host_s = 0.0         # digest processing / bookkeeping
+        # host/device time split, each the sum of the spans (core.spans)
+        # that name it: pack + async dispatch (host), blocked
+        # materializing digests, digest processing / bookkeeping
+        self.t_dispatch = Acc()
+        self.t_sync = Acc()
+        self.t_host = Acc()
         # host-time breakdown (disjoint buckets inside the above):
         # admission / digest fold / query retirement / pattern flush
-        self.t_admit_s = 0.0
-        self.t_digest_s = 0.0
-        self.t_retire_s = 0.0
-        self.t_flush_s = 0.0
+        self.t_admit = Acc()
+        self.t_flush = Acc()
+        self.t_retire = Acc(self.t_flush)
+        self.t_digest = Acc(self.t_retire, self.t_flush)
+        # candidate filtering and ordering at submit (_prepare)
+        self.t_prepare = Acc()
+        self.prepared = 0
         # ---- fault tolerance (DESIGN.md §8) ---------------------------
         # every hook below is gated on its knob (or ``_faults is None``)
         # so the disabled path costs one attribute load per boundary
@@ -398,7 +407,7 @@ class WaveScheduler:
                options: MatchOptions | None = None,
                cand: list[np.ndarray] | None = None,
                order: np.ndarray | None = None,
-               on_embeddings=None, **overrides) -> int:
+               on_embeddings=None, on_admit=None, **overrides) -> int:
         """Enqueue a query; returns its scheduler query id.
 
         Per-query knobs (``limit``, ``time_budget_s``,
@@ -425,6 +434,9 @@ class WaveScheduler:
         digest is processed (not at retirement) — the plumbing behind
         ``MatchHandle.stream()``.
 
+        ``on_admit``: called with no argument when the query first takes
+        a slot (the end of its time in the queue).
+
         ``seed_patterns``: a pattern *entries* dict (patterns.store) to
         pre-load into the query's slot, hit counters included (cross-host
         pattern import or checkpoint restore — see core.distributed).
@@ -447,60 +459,64 @@ class WaveScheduler:
         t_submit = time.perf_counter()
         qid = self._next_qid
         self._next_qid += 1
-        cand_by_pos, order, _pos_of, nbr_pos = _prepare(
-            query, self.data, cand, order)
-        n = query.n
-        v = self.data.n
-        cand_dense = np.zeros((N_PAD, v), bool)
-        for d in range(n):
-            cand_dense[d, cand_by_pos[d]] = True
-        nbr_mask = np.zeros((N_PAD, N_PAD), bool)
-        qnbr_bits = np.zeros(N_PAD, np.uint64)
-        for d in range(n):
-            bits = np.uint64(0)
-            for p in nbr_pos[d]:
-                nbr_mask[d, int(p)] = True
-                bits |= bit_of(int(p))
-            qnbr_bits[d] = bits
-        learn = (self.use_pruning if opts.use_pruning is None
-                 else opts.use_pruning)
-        cand_packed = pack_bitmap(cand_dense)
-        req = _Request(
-            query_id=qid, n=n, order=np.asarray(order, np.int32),
-            roots=np.asarray(cand_by_pos[0], np.int32),
-            cand_bitmap=cand_packed, nbr_mask=nbr_mask,
-            qnbr_bits=qnbr_bits, limit=opts.limit, learn=learn,
-            max_rows=opts.max_recursions,
-            time_budget_s=opts.time_budget_s,
-            seed_patterns=opts.seed_patterns, keep_table=opts.keep_table,
-            t_submit=t_submit, fingerprint=None,
-            parallelism=max(1, int(opts.parallelism)),
-            priority=int(opts.priority), on_embeddings=on_embeddings)
-        # trivial queries never need a slot (and never touch the cache)
-        if len(req.roots) == 0 or n == 1:
-            self._finish_trivial(req)
-        else:
-            # the fingerprint digests the packed candidate bitmap — not
-            # free at web-scale V, so only queries that can actually
-            # consume the cache (learning, cache enabled) pay for it
-            if self.pattern_cache is not None and learn:
-                req.fingerprint = PatternCache.fingerprint(
-                    n, cand_packed, nbr_mask)
-            if len(self.queue) >= self.max_queue:
-                # shed_lowest overload policy: the overall lowest-
-                # priority request — queued or the new arrival, newest
-                # within a tie — completes immediately with
-                # status="shed" instead of growing the queue (or
-                # rejecting a high-priority arrival behind low traffic)
-                victim = min(range(len(self.queue)),
-                             key=lambda i: (self.queue[i].priority, -i))
-                if req.priority <= self.queue[victim].priority:
-                    self._shed_request(req)
-                    return qid
-                shed_req = self.queue[victim]
-                del self.queue[victim]
-                self._shed_request(shed_req)
-            self.queue.append(req)
+        with span(spans.SCHED_SUBMIT, query_id=qid):
+            with span(spans.SCHED_PREPARE, self.t_prepare, query_id=qid):
+                cand_by_pos, order, _pos_of, nbr_pos = _prepare(
+                    query, self.data, cand, order)
+            self.prepared += 1
+            n = query.n
+            v = self.data.n
+            cand_dense = np.zeros((N_PAD, v), bool)
+            for d in range(n):
+                cand_dense[d, cand_by_pos[d]] = True
+            nbr_mask = np.zeros((N_PAD, N_PAD), bool)
+            qnbr_bits = np.zeros(N_PAD, np.uint64)
+            for d in range(n):
+                bits = np.uint64(0)
+                for p in nbr_pos[d]:
+                    nbr_mask[d, int(p)] = True
+                    bits |= bit_of(int(p))
+                qnbr_bits[d] = bits
+            learn = (self.use_pruning if opts.use_pruning is None
+                     else opts.use_pruning)
+            cand_packed = pack_bitmap(cand_dense)
+            req = _Request(
+                query_id=qid, n=n, order=np.asarray(order, np.int32),
+                roots=np.asarray(cand_by_pos[0], np.int32),
+                cand_bitmap=cand_packed, nbr_mask=nbr_mask,
+                qnbr_bits=qnbr_bits, limit=opts.limit, learn=learn,
+                max_rows=opts.max_recursions,
+                time_budget_s=opts.time_budget_s,
+                seed_patterns=opts.seed_patterns, keep_table=opts.keep_table,
+                t_submit=t_submit, fingerprint=None,
+                parallelism=max(1, int(opts.parallelism)),
+                priority=int(opts.priority), on_embeddings=on_embeddings,
+                on_admit=on_admit)
+            # trivial queries never need a slot (and never touch the cache)
+            if len(req.roots) == 0 or n == 1:
+                self._finish_trivial(req)
+            else:
+                # the fingerprint digests the packed candidate bitmap — not
+                # free at web-scale V, so only queries that can actually
+                # consume the cache (learning, cache enabled) pay for it
+                if self.pattern_cache is not None and learn:
+                    req.fingerprint = PatternCache.fingerprint(
+                        n, cand_packed, nbr_mask)
+                if len(self.queue) >= self.max_queue:
+                    # shed_lowest overload policy: the overall lowest-
+                    # priority request — queued or the new arrival, newest
+                    # within a tie — completes immediately with
+                    # status="shed" instead of growing the queue (or
+                    # rejecting a high-priority arrival behind low traffic)
+                    victim = min(range(len(self.queue)),
+                                 key=lambda i: (self.queue[i].priority, -i))
+                    if req.priority <= self.queue[victim].priority:
+                        self._shed_request(req)
+                        return qid
+                    shed_req = self.queue[victim]
+                    del self.queue[victim]
+                    self._shed_request(shed_req)
+                self.queue.append(req)
         return qid
 
     def _shed_request(self, req: _Request) -> None:
@@ -593,9 +609,10 @@ class WaveScheduler:
                     # the template recurred: materialize the deferred
                     # snapshot into its cache line now
                     snap_store, snap_hits = pend
-                    self.pattern_cache.put(
-                        req.fingerprint,
-                        store_to_entries(snap_store, snap_hits))
+                    with span(spans.SCHED_READBACK):
+                        self.pattern_cache.put(
+                            req.fingerprint,
+                            store_to_entries(snap_store, snap_hits))
                 entries = self.pattern_cache.get(req.fingerprint)
                 warm = entries is not None
             if entries is not None and len(entries["pos"]) > 0:
@@ -668,6 +685,8 @@ class WaveScheduler:
             else:
                 self._admit_host_roots(q, req.roots)
             self.pool.attach(slot, q)
+            if req.on_admit is not None:
+                req.on_admit()
         self._flush_slot_loads(loads, dev_clears)
 
     def _flush_slot_loads(self, loads: list[tuple],
@@ -770,81 +789,82 @@ class WaveScheduler:
     # completion / abort / cancellation
     # ------------------------------------------------------------------
     def _finish(self, q: QueryState) -> None:
-        t0 = time.perf_counter()
-        f0 = self.t_flush_s
-        self._deliver(q)
-        q.materialize_hits()
-        want_cache = (self.pattern_cache is not None and q.learn
-                      and q.fingerprint is not None)
-        if (q.keep_table or want_cache) and q.store_buf:
-            # make patterns from the final resolutions visible in the
-            # snapshot (distributed sharing / template cache)
-            self._flush_stores(force=True)
-        # materialize AFTER the final flush: the retiring query's last
-        # insert counters must fold while it still owns its slot
-        self._materialize_flush_counters()
-        q.status = "done"
-        q.evict()
-        q.stats.recursions = q.stats.rows_created
-        q.stats.wall_time_s = time.perf_counter() - q.t_submit
-        if q.parallelism > 1:
-            q.stats.shard_rows = q.shard_rows.tolist()
-            q.stats.shard_items = q.shard_items.tolist()
-        self.total_prunes += q.stats.deadend_prunes
-        self.total_rows_created += q.stats.rows_created
-        self.total_steals += q.stats.steals
-        ts = q.stats.table_stats
-        if isinstance(ts, DeadEndStats):
-            # hits = Δ prunes; lookups stays 0 on the engine path
-            # (see DeadEndStats — the digest has no lookup count)
-            ts.hits = q.stats.deadend_prunes
-        if q.keep_table:
-            entries = store_to_entries(read_store_slot(self.tb, q.slot),
-                                       q.hit_counts)
+        with span(spans.SCHED_FINISH, self.t_retire,
+                  query_id=q.query_id):
+            self._deliver(q)
+            q.materialize_hits()
+            want_cache = (self.pattern_cache is not None and q.learn
+                          and q.fingerprint is not None)
+            if (q.keep_table or want_cache) and q.store_buf:
+                # make patterns from the final resolutions visible in the
+                # snapshot (distributed sharing / template cache)
+                self._flush_stores(force=True)
+            # materialize AFTER the final flush: the retiring query's last
+            # insert counters must fold while it still owns its slot
+            self._materialize_flush_counters()
+            q.status = "done"
+            q.evict()
+            q.stats.recursions = q.stats.rows_created
+            q.stats.wall_time_s = time.perf_counter() - q.t_submit
+            if q.parallelism > 1:
+                q.stats.shard_rows = q.shard_rows.tolist()
+                q.stats.shard_items = q.shard_items.tolist()
+            self.total_prunes += q.stats.deadend_prunes
+            self.total_rows_created += q.stats.rows_created
+            self.total_steals += q.stats.steals
+            ts = q.stats.table_stats
             if isinstance(ts, DeadEndStats):
-                ts.occupancy = len(entries["pos"])
-            self.tables[q.query_id] = entries
-            if want_cache:
-                # already materialized for the table export — fold the
-                # retiring learner's hot transferable patterns into the
-                # template's cache line right away
-                self.pattern_cache.put(q.fingerprint, entries)
-        elif want_cache:
-            # defer: capture the slot store as async device slices (no
-            # pipeline stall here) — materialized into a cache line
-            # only if the same template is admitted again
-            snap = read_store_slot(self.tb, q.slot)
-            hits = dict(q.hit_counts) if q.hit_counts is not None else None
-            prev = self._pending_snaps.pop(q.fingerprint, None)
-            if prev is not None:
-                # same template already has a pending snapshot (e.g. a
-                # richer earlier run): fold it into the cache line —
-                # put() merges by key — instead of discarding it
-                self.pattern_cache.put(q.fingerprint,
-                                       store_to_entries(*prev))
-            self._pending_snaps[q.fingerprint] = (snap, hits)
-            # tight bound: each pending snapshot pins a full-capacity
-            # slice set on device (unlike the top_k-capped cache lines),
-            # so size to the slot count, not to max_templates. An
-            # LRU-evicted snapshot is materialized into its (compact)
-            # cache line rather than discarded — otherwise interleaved
-            # traffic over more templates than the pending bound would
-            # never populate the cache at all.
-            while len(self._pending_snaps) > max(8, 2 * self.n_slots):
-                old_fp, (old_snap, old_hits) = \
-                    self._pending_snaps.popitem(last=False)
-                self.pattern_cache.put(
-                    old_fp, store_to_entries(old_snap, old_hits))
-        self.finished[q.query_id] = MatchResult(q.embeddings, q.stats)
-        self._fresh_done.append(q.query_id)
-        if getattr(q, "device", False) and self.sb is not None:
-            # release the slot's device stack; the clear chains in
-            # program order after any in-flight dispatch (the handle is
-            # that dispatch's output), so live entries cannot revive
-            self.sb = clear_slot_stack(self.sb, np.int32(q.slot))
-        self.pool.release(q.slot)
-        self.t_retire_s += (time.perf_counter() - t0
-                            - (self.t_flush_s - f0))
+                # hits = Δ prunes; lookups stays 0 on the engine path
+                # (see DeadEndStats — the digest has no lookup count)
+                ts.hits = q.stats.deadend_prunes
+            if q.keep_table:
+                with span(spans.SCHED_READBACK):
+                    entries = store_to_entries(
+                        read_store_slot(self.tb, q.slot), q.hit_counts)
+                if isinstance(ts, DeadEndStats):
+                    ts.occupancy = len(entries["pos"])
+                self.tables[q.query_id] = entries
+                if want_cache:
+                    # already materialized for the table export — fold the
+                    # retiring learner's hot transferable patterns into the
+                    # template's cache line right away
+                    self.pattern_cache.put(q.fingerprint, entries)
+            elif want_cache:
+                # defer: capture the slot store as async device slices (no
+                # pipeline stall here) — materialized into a cache line
+                # only if the same template is admitted again
+                snap = read_store_slot(self.tb, q.slot)
+                hits = dict(q.hit_counts) if q.hit_counts is not None else None
+                prev = self._pending_snaps.pop(q.fingerprint, None)
+                if prev is not None:
+                    # same template already has a pending snapshot (e.g. a
+                    # richer earlier run): fold it into the cache line —
+                    # put() merges by key — instead of discarding it
+                    with span(spans.SCHED_READBACK):
+                        self.pattern_cache.put(q.fingerprint,
+                                               store_to_entries(*prev))
+                self._pending_snaps[q.fingerprint] = (snap, hits)
+                # tight bound: each pending snapshot pins a full-capacity
+                # slice set on device (unlike the top_k-capped cache lines),
+                # so size to the slot count, not to max_templates. An
+                # LRU-evicted snapshot is materialized into its (compact)
+                # cache line rather than discarded — otherwise interleaved
+                # traffic over more templates than the pending bound would
+                # never populate the cache at all.
+                while len(self._pending_snaps) > max(8, 2 * self.n_slots):
+                    old_fp, (old_snap, old_hits) = \
+                        self._pending_snaps.popitem(last=False)
+                    with span(spans.SCHED_READBACK):
+                        self.pattern_cache.put(
+                            old_fp, store_to_entries(old_snap, old_hits))
+            self.finished[q.query_id] = MatchResult(q.embeddings, q.stats)
+            self._fresh_done.append(q.query_id)
+            if getattr(q, "device", False) and self.sb is not None:
+                # release the slot's device stack; the clear chains in
+                # program order after any in-flight dispatch (the handle is
+                # that dispatch's output), so live entries cannot revive
+                self.sb = clear_slot_stack(self.sb, np.int32(q.slot))
+            self.pool.release(q.slot)
 
     def _abort(self, q: QueryState, reason: str) -> None:
         """Abort a query (budget exhausted or limit reached) and evict
@@ -1271,10 +1291,11 @@ class WaveScheduler:
     def _fold_store_counters(self, counters, slot_map: dict | None) -> None:
         """Fold per-slot device insert counters (int32 [S] lanes) into
         the scheduler totals and the owning queries' DeadEndStats."""
-        lanes = {"stored": np.asarray(counters[0], np.int64),
-                 "overwrites": np.asarray(counters[1], np.int64),
-                 "evictions": np.asarray(counters[2], np.int64),
-                 "dropped": np.asarray(counters[3], np.int64)}
+        with span(spans.SCHED_READBACK):
+            lanes = {"stored": np.asarray(counters[0], np.int64),
+                     "overwrites": np.asarray(counters[1], np.int64),
+                     "evictions": np.asarray(counters[2], np.int64),
+                     "dropped": np.asarray(counters[3], np.int64)}
         for k, v in lanes.items():
             self.store_counters[k] += int(v.sum())
         if slot_map is None:
@@ -1297,32 +1318,28 @@ class WaveScheduler:
         bufs = self._pending_stores()
         if not bufs:
             return
-        t0 = time.perf_counter()
-        if not self.pool.learning_enabled:
-            for q, buf in bufs:
-                buf.clear()
-            self.t_flush_s += time.perf_counter() - t0
-            return
-        total = sum(len(buf) for _, buf in bufs)
-        if not force and total < self.store_flush_min:
-            self.t_flush_s += time.perf_counter() - t0
-            return
-        dedup = self._drain_dedup(bufs, None)
-        if self._faults is not None and dedup and self._faults.poke(
-                "flush", n=len(dedup)) is not None:
-            # injected flush failure: drop the batch — sound, patterns
-            # only ever prune
-            self.fault_counters["flush_drops"] += 1
-            self.t_flush_s += time.perf_counter() - t0
-            return
-        n_pad = 16
-        while n_pad < len(dedup):
-            n_pad *= 2
-        self.tb, counters = store_patterns_mq(
-            self.tb, *self._pack_store_batch(dedup, n_pad))
-        self._flush_ctr_dev = (counters if self._flush_ctr_dev is None
-                               else self._flush_ctr_dev.add(counters))
-        self.t_flush_s += time.perf_counter() - t0
+        with span(spans.STORE_FLUSH, self.t_flush):
+            if not self.pool.learning_enabled:
+                for q, buf in bufs:
+                    buf.clear()
+                return
+            total = sum(len(buf) for _, buf in bufs)
+            if not force and total < self.store_flush_min:
+                return
+            dedup = self._drain_dedup(bufs, None)
+            if self._faults is not None and dedup and self._faults.poke(
+                    "flush", n=len(dedup)) is not None:
+                # injected flush failure: drop the batch — sound, patterns
+                # only ever prune
+                self.fault_counters["flush_drops"] += 1
+                return
+            n_pad = 16
+            while n_pad < len(dedup):
+                n_pad *= 2
+            self.tb, counters = store_patterns_mq(
+                self.tb, *self._pack_store_batch(dedup, n_pad))
+            self._flush_ctr_dev = (counters if self._flush_ctr_dev is None
+                                   else self._flush_ctr_dev.add(counters))
 
     def _materialize_flush_counters(self) -> None:
         """Fold the accumulated flush counters into stats. Correct
@@ -1338,25 +1355,24 @@ class WaveScheduler:
         """Drain up to ``store_pad`` host-queued pattern stores into the
         fixed-length arrays that ride the next megastep dispatch.
         Leftover entries stay queued for the next wave."""
-        t0 = time.perf_counter()
-        bufs = self._pending_stores()
-        if not self.pool.learning_enabled:
-            for q, buf in bufs:
-                buf.clear()
-            bufs = []
-        dedup = self._drain_dedup(bufs, self.store_pad)
-        if self._faults is not None and dedup and self._faults.poke(
-                "flush", n=len(dedup)) is not None:
-            # injected flush failure: drop the pattern batch (sound)
-            self.fault_counters["flush_drops"] += 1
-            dedup = {}
-        out = self._pack_store_batch(dedup, self.store_pad)
-        self.t_flush_s += time.perf_counter() - t0
-        return out
+        with span(spans.STORE_FLUSH, self.t_flush):
+            bufs = self._pending_stores()
+            if not self.pool.learning_enabled:
+                for q, buf in bufs:
+                    buf.clear()
+                bufs = []
+            dedup = self._drain_dedup(bufs, self.store_pad)
+            if self._faults is not None and dedup and self._faults.poke(
+                    "flush", n=len(dedup)) is not None:
+                # injected flush failure: drop the pattern batch (sound)
+                self.fault_counters["flush_drops"] += 1
+                dedup = {}
+            return self._pack_store_batch(dedup, self.store_pad)
 
     # ------------------------------------------------------------------
     # one scheduling step (double-buffered pipeline)
     # ------------------------------------------------------------------
+    @spans.traced(spans.SCHED_STEP)
     def step(self) -> bool:
         """Admit, pack, and execute one wave. Returns False when idle.
 
@@ -1366,9 +1382,8 @@ class WaveScheduler:
         device compute (double buffering).
         """
         self._check_budgets()
-        t_a = time.perf_counter()
-        self._admit()
-        self.t_admit_s += time.perf_counter() - t_a
+        with span(spans.SCHED_ADMIT, self.t_admit):
+            self._admit()
         if self.waves - self._last_aged_wave >= self.hit_decay_every:
             # age the device hit counters so eviction ranks *recent*
             # usefulness (stale hot entries decay back into candidates);
@@ -1392,10 +1407,9 @@ class WaveScheduler:
             sync_dev, self._inflight_dev = self._inflight_dev, None
             self._retire_device(sync_dev)
             retired_dev = True
-        t0 = time.perf_counter()
-        rec_dev = self._dispatch_device(
-            1 if ema_high else self.megastep_depth)
-        self.t_dispatch_s += time.perf_counter() - t0
+        with span(spans.SCHED_DISPATCH_DEVICE, self.t_dispatch):
+            rec_dev = self._dispatch_device(
+                1 if ema_high else self.megastep_depth)
         prev_dev, self._inflight_dev = self._inflight_dev, rec_dev
         if ema_high:
             # failure-heavy regime: drain the pipeline and fall back to
@@ -1409,15 +1423,14 @@ class WaveScheduler:
                     self._retire_leftover(prev)
             progressed = self._step_single() or prev is not None
         else:
-            t0 = time.perf_counter()
-            picks = self._pack_wave()
-            rec: _Inflight | None = None
-            if picks is not None:
-                if self._wave_kind == "fresh":
-                    rec = self._dispatch_mega(picks)
-                else:
-                    rec = self._dispatch_leftover(picks)
-            self.t_dispatch_s += time.perf_counter() - t0
+            with span(spans.SCHED_DISPATCH_WAVE, self.t_dispatch):
+                picks = self._pack_wave()
+                rec: _Inflight | None = None
+                if picks is not None:
+                    if self._wave_kind == "fresh":
+                        rec = self._dispatch_mega(picks)
+                    else:
+                        rec = self._dispatch_leftover(picks)
             prev, self._inflight = self._inflight, rec
             if prev is not None:
                 if prev.kind == "mega":
@@ -1525,6 +1538,7 @@ class WaveScheduler:
             if q.active:
                 self._quarantine(q, msg)
 
+    @spans.traced(spans.SCHED_RETIRE_DEVICE)
     def _retire_device(self, rec: _InflightDev) -> None:
         """Fold one device-resident digest: per-slot scalars into query
         stats (no per-row lanes exist), the embedding batch out to the
@@ -1536,155 +1550,151 @@ class WaveScheduler:
                                 stacks=True)
             return
         res = rec.res
-        t0 = time.perf_counter()
-        dig = {k: np.asarray(getattr(res, k)) for k in _DEV_LANES}
-        n_emb = max(0, min(int(res.n_emb), self._emb_cap))
-        embF = np.asarray(res.emb_frontier)[:n_emb]
-        embS = np.asarray(res.emb_slot)[:n_emb]
-        t1 = time.perf_counter()
-        self.t_sync_s += t1 - t0
-        if (self.dispatch_timeout_s is not None
-                and t1 - rec.t_dispatch > self.dispatch_timeout_s):
-            # per-dispatch watchdog: the call blocked past its deadline
-            # — whatever it returned is not worth trusting over a clean
-            # restart of the involved queries
-            self.fault_counters["hangs"] += 1
-            self._watchdog_fire(
-                rec.slot_map, "dispatch exceeded watchdog deadline "
-                f"({self.dispatch_timeout_s:g}s)", stacks=True)
-            return
-        if self._faults is not None:
-            slots = sorted(s for s, q in rec.slot_map.items()
-                           if q.active and q.device)
-            spec = (self._faults.poke("digest", slots=slots)
-                    if slots else None)
-            if spec is not None:
-                dig = {k: np.array(v) for k, v in dig.items()}
-                corrupt_digest(dig, spec,
-                               stack_capacity=self.stack_capacity,
-                               slots=slots)
-        if self.validate_digests:
-            bad, global_bad = self._validate_device_digest(
-                dig, int(res.n_emb), embS, embF, rec.slot_map)
-            if global_bad:
-                self.fault_counters["digest_failures"] += 1
-                self._watchdog_fire(rec.slot_map,
-                                    "device digest globally invalid",
-                                    stacks=True)
+        with span(spans.SCHED_READBACK, self.t_sync):
+            dig = {k: np.asarray(getattr(res, k)) for k in _DEV_LANES}
+            n_emb = max(0, min(int(res.n_emb), self._emb_cap))
+            embF = np.asarray(res.emb_frontier)[:n_emb]
+            embS = np.asarray(res.emb_slot)[:n_emb]
+        with span(spans.SCHED_DIGEST, (self.t_host, self.t_digest)):
+            if (self.dispatch_timeout_s is not None
+                    and time.perf_counter() - rec.t_dispatch
+                    > self.dispatch_timeout_s):
+                # per-dispatch watchdog: the call blocked past its deadline
+                # — whatever it returned is not worth trusting over a clean
+                # restart of the involved queries
+                self.fault_counters["hangs"] += 1
+                self._watchdog_fire(
+                    rec.slot_map, "dispatch exceeded watchdog deadline "
+                    f"({self.dispatch_timeout_s:g}s)", stacks=True)
                 return
-            if bad:
-                # quarantine each failing slot's query and zero its
-                # lanes/rows so the aggregate folds below stay clean —
-                # neighbors' digests (and embedding rows) are untouched
-                dig = {k: (v if v.flags.writeable else v.copy())
-                       for k, v in dig.items()}
-                for slot, why in bad.items():
+            if self._faults is not None:
+                slots = sorted(s for s, q in rec.slot_map.items()
+                               if q.active and q.device)
+                spec = (self._faults.poke("digest", slots=slots)
+                        if slots else None)
+                if spec is not None:
+                    dig = {k: np.array(v) for k, v in dig.items()}
+                    corrupt_digest(dig, spec,
+                                   stack_capacity=self.stack_capacity,
+                                   slots=slots)
+            if self.validate_digests:
+                bad, global_bad = self._validate_device_digest(
+                    dig, int(res.n_emb), embS, embF, rec.slot_map)
+                if global_bad:
                     self.fault_counters["digest_failures"] += 1
-                    q = rec.slot_map[slot]
-                    for k in _DEV_LANES:
-                        dig[k][slot] = 0
+                    self._watchdog_fire(rec.slot_map,
+                                        "device digest globally invalid",
+                                        stacks=True)
+                    return
+                if bad:
+                    # quarantine each failing slot's query and zero its
+                    # lanes/rows so the aggregate folds below stay clean —
+                    # neighbors' digests (and embedding rows) are untouched
+                    dig = {k: (v if v.flags.writeable else v.copy())
+                           for k, v in dig.items()}
+                    for slot, why in bad.items():
+                        self.fault_counters["digest_failures"] += 1
+                        q = rec.slot_map[slot]
+                        for k in _DEV_LANES:
+                            dig[k][slot] = 0
+                        if q.active:
+                            self._quarantine(
+                                q, f"digest validation failed: {why}")
+                    if len(embS):
+                        keep = ~np.isin(embS, list(bad))
+                        embF, embS = embF[keep], embS[keep]
+            n_emb = len(embS)
+            d_accepted = dig["d_accepted"]
+            d_expanded = dig["d_expanded"]
+            d_rows = dig["d_rows"]
+            d_prunes = dig["d_prunes"]
+            d_inj = dig["d_inj"]
+            d_stored = dig["d_stored"]
+            d_pending = dig["d_pending"]
+            d_live = dig["d_live"]
+
+            self._fold_store_counters(
+                (res.pat_stored, res.pat_overwrites, res.pat_evictions,
+                 res.pat_dropped), rec.slot_map)
+            self.slot_rows_expanded += d_expanded.astype(np.int64)
+            self.slot_children_created += d_rows.astype(np.int64)
+            expanded_total = int(d_expanded.sum())
+            worked = bool(expanded_total or n_emb or d_accepted.sum())
+            if worked:
+                self.rows_packed += expanded_total
+                occ = min(1.0, expanded_total / (self.wave_size * rec.t_max))
+                self.occ_sum += occ
+                self.waves += 1
+                for q in rec.slot_map.values():
                     if q.active:
-                        self._quarantine(
-                            q, f"digest validation failed: {why}")
-                if len(embS):
-                    keep = ~np.isin(embS, list(bad))
-                    embF, embS = embF[keep], embS[keep]
-        n_emb = len(embS)
-        d_accepted = dig["d_accepted"]
-        d_expanded = dig["d_expanded"]
-        d_rows = dig["d_rows"]
-        d_prunes = dig["d_prunes"]
-        d_inj = dig["d_inj"]
-        d_stored = dig["d_stored"]
-        d_pending = dig["d_pending"]
-        d_live = dig["d_live"]
-        r0, f0 = self.t_retire_s, self.t_flush_s
+                        q.stats.waves += 1
+                if self.pool.n_active == self.n_slots:
+                    self.waves_steady += 1
+                    self.occ_sum_steady += occ
 
-        self._fold_store_counters(
-            (res.pat_stored, res.pat_overwrites, res.pat_evictions,
-             res.pat_dropped), rec.slot_map)
-        self.slot_rows_expanded += d_expanded.astype(np.int64)
-        self.slot_children_created += d_rows.astype(np.int64)
-        expanded_total = int(d_expanded.sum())
-        worked = bool(expanded_total or n_emb or d_accepted.sum())
-        if worked:
-            self.rows_packed += expanded_total
-            occ = min(1.0, expanded_total / (self.wave_size * rec.t_max))
-            self.occ_sum += occ
-            self.waves += 1
-            for q in rec.slot_map.values():
-                if q.active:
-                    q.stats.waves += 1
-            if self.pool.n_active == self.n_slots:
-                self.waves_steady += 1
-                self.occ_sum_steady += occ
+            emb_per_slot = (np.bincount(embS, minlength=self.n_slots)
+                            if n_emb else np.zeros(self.n_slots, np.int64))
 
-        emb_per_slot = (np.bincount(embS, minlength=self.n_slots)
-                        if n_emb else np.zeros(self.n_slots, np.int64))
-
-        # ---- per-query scalar digest fold ------------------------------
-        for slot, q in rec.slot_map.items():
-            if not q.active or not getattr(q, "device", False):
-                continue
-            q.stats.rows_created += int(d_rows[slot])
-            q.stats.deadend_prunes += int(d_prunes[slot])
-            q.stats.injectivity_fails += int(d_inj[slot])
-            q.stats.patterns_stored += int(d_stored[slot])
-            if q.dev_roots_inflight and slot in rec.root_slots:
-                q.root_cursor += int(d_accepted[slot])
-                q.dev_roots_inflight = False
-
-        # ---- embeddings found on device (+ limit aborts) ---------------
-        if n_emb:
-            for sl_v in np.unique(embS):
-                q = rec.slot_map.get(int(sl_v))
-                if q is None or not q.active:
+            # ---- per-query scalar digest fold ------------------------------
+            for slot, q in rec.slot_map.items():
+                if not q.active or not getattr(q, "device", False):
                     continue
-                self._fold_embeddings(q, embF[embS == sl_v])
-                if q.limit is not None and q.stats.found >= q.limit:
-                    self._abort(q, "limit")
+                q.stats.rows_created += int(d_rows[slot])
+                q.stats.deadend_prunes += int(d_prunes[slot])
+                q.stats.injectivity_fails += int(d_inj[slot])
+                q.stats.patterns_stored += int(d_stored[slot])
+                if q.dev_roots_inflight and slot in rec.root_slots:
+                    q.root_cursor += int(d_accepted[slot])
+                    q.dev_roots_inflight = False
 
-        # ---- completion / budget / wedge checks ------------------------
-        for slot, q in rec.slot_map.items():
-            if not q.active or not getattr(q, "device", False):
-                continue
-            if (q.max_rows is not None
-                    and q.stats.rows_created > q.max_rows):
-                self._abort(q, "rows")
-                continue
-            roots_done = (q.root_cursor >= len(q.pending_roots)
-                          and not q.dev_roots_inflight)
-            if (roots_done and d_pending[slot] == 0
-                    and d_live[slot] == 0):
-                # done — any embedding batch that landed this retire was
-                # already streamed above (the embedding fold runs before
-                # this loop), so consumers observe delivery-then-done
-                # within the same retire and no trailing empty dispatch
-                # is needed to finish the query
-                self._finish(q)
-                continue
-            # wedge detection: a full stack can throttle to a state
-            # where iterations select rows but nothing allocates,
-            # resolves, embeds or stores. After 3 observably identical
-            # digests, export the stack back to host segments.
-            moved = (int(d_accepted[slot]) or int(d_rows[slot])
-                     or int(emb_per_slot[slot]) or int(d_stored[slot])
-                     or int(d_prunes[slot]))
-            sig = (int(d_pending[slot]), int(d_live[slot]))
-            if moved or sig != q.dev_sig:
-                q.dev_wedge = 0
-            else:
-                q.dev_wedge += 1
-            q.dev_sig = sig
-            if q.dev_wedge >= 3:
-                self._export_device_query(q)
-        if worked:
-            self._note_prunes(int(d_prunes.sum()), int(d_rows.sum()))
-        dt = time.perf_counter() - t1
-        self.t_host_s += dt
-        self.t_digest_s += max(0.0, dt - (self.t_retire_s - r0)
-                               - (self.t_flush_s - f0))
+            # ---- embeddings found on device (+ limit aborts) ---------------
+            if n_emb:
+                for sl_v in np.unique(embS):
+                    q = rec.slot_map.get(int(sl_v))
+                    if q is None or not q.active:
+                        continue
+                    self._fold_embeddings(q, embF[embS == sl_v])
+                    if q.limit is not None and q.stats.found >= q.limit:
+                        self._abort(q, "limit")
 
+            # ---- completion / budget / wedge checks ------------------------
+            for slot, q in rec.slot_map.items():
+                if not q.active or not getattr(q, "device", False):
+                    continue
+                if (q.max_rows is not None
+                        and q.stats.rows_created > q.max_rows):
+                    self._abort(q, "rows")
+                    continue
+                roots_done = (q.root_cursor >= len(q.pending_roots)
+                              and not q.dev_roots_inflight)
+                if (roots_done and d_pending[slot] == 0
+                        and d_live[slot] == 0):
+                    # done — any embedding batch that landed this retire was
+                    # already streamed above (the embedding fold runs before
+                    # this loop), so consumers observe delivery-then-done
+                    # within the same retire and no trailing empty dispatch
+                    # is needed to finish the query
+                    self._finish(q)
+                    continue
+                # wedge detection: a full stack can throttle to a state
+                # where iterations select rows but nothing allocates,
+                # resolves, embeds or stores. After 3 observably identical
+                # digests, export the stack back to host segments.
+                moved = (int(d_accepted[slot]) or int(d_rows[slot])
+                         or int(emb_per_slot[slot]) or int(d_stored[slot])
+                         or int(d_prunes[slot]))
+                sig = (int(d_pending[slot]), int(d_live[slot]))
+                if moved or sig != q.dev_sig:
+                    q.dev_wedge = 0
+                else:
+                    q.dev_wedge += 1
+                q.dev_sig = sig
+                if q.dev_wedge >= 3:
+                    self._export_device_query(q)
+            if worked:
+                self._note_prunes(int(d_prunes.sum()), int(d_rows.sum()))
+
+    @spans.traced(spans.SCHED_EXPORT)
     def _export_device_query(self, q: QueryState) -> None:
         """Wedge fallback: materialize one slot's device stack back into
         host segments (one 1-row segment per live entry, parent links
@@ -1699,21 +1709,23 @@ class WaveScheduler:
             # and drop its digest for this query at retire time
             if (q.dev_roots_inflight
                     and slot in self._inflight_dev.root_slots):
-                q.root_cursor += int(np.asarray(
-                    self._inflight_dev.res.d_accepted)[slot])
+                with span(spans.SCHED_READBACK):
+                    q.root_cursor += int(np.asarray(
+                        self._inflight_dev.res.d_accepted)[slot])
         q.dev_roots_inflight = False
         q.device = False
         sb = self.sb
-        st = np.asarray(sb.state[slot])
-        frontier = np.asarray(sb.frontier[slot])
-        used = np.asarray(sb.used[slot])
-        phi = np.asarray(sb.phi[slot])
-        depth = np.asarray(sb.depth[slot])
-        cand = np.asarray(sb.cand[slot])
-        gamma64 = mask64(np.asarray(sb.gamma[slot]))
-        outstanding = np.asarray(sb.outstanding[slot])
-        reported = np.asarray(sb.reported[slot])
-        parent = np.asarray(sb.parent[slot])
+        with span(spans.SCHED_READBACK):
+            st = np.asarray(sb.state[slot])
+            frontier = np.asarray(sb.frontier[slot])
+            used = np.asarray(sb.used[slot])
+            phi = np.asarray(sb.phi[slot])
+            depth = np.asarray(sb.depth[slot])
+            cand = np.asarray(sb.cand[slot])
+            gamma64 = mask64(np.asarray(sb.gamma[slot]))
+            outstanding = np.asarray(sb.outstanding[slot])
+            reported = np.asarray(sb.reported[slot])
+            parent = np.asarray(sb.parent[slot])
         live = np.nonzero(st != STK_FREE)[0]
         seg_of: dict[int, Segment] = {}
         for e in live.tolist():
@@ -1791,6 +1803,7 @@ class WaveScheduler:
         return _Inflight("mega", res, metas, slot_map,
                          t_dispatch=time.perf_counter(), hung=hung)
 
+    @spans.traced(spans.SCHED_RETIRE_WAVE)
     def _retire_mega(self, rec: _Inflight) -> None:
         if rec.hung:
             self._watchdog_fire(
@@ -1798,205 +1811,204 @@ class WaveScheduler:
                 "injected dispatch hang", stacks=False)
             return
         res: MegaResult = rec.res
-        t0 = time.perf_counter()
-        head = int(res.head)
-        tail = int(res.tail)
-        bufF = np.asarray(res.buf_frontier)
-        bufU = np.asarray(res.buf_used)
-        bufP = np.asarray(res.buf_phi)
-        slot_a = np.asarray(res.buf_slot)
-        depth_a = np.asarray(res.buf_depth)
-        parent_a = np.asarray(res.buf_parent)
-        valid_a = np.asarray(res.buf_valid)
-        rempty = np.asarray(res.refined_empty)
-        nchild = np.asarray(res.n_children)
-        nleft = np.asarray(res.n_leftover)
-        leftover = np.asarray(res.leftover)
-        pmask = mask64(np.asarray(res.partial_mask))
-        nprun = np.asarray(res.n_pruned)
-        ninj = np.asarray(res.n_inj)
-        nembr = np.asarray(res.n_emb_row)
-        dstored = np.asarray(res.dev_stored)
-        pruned_v = np.asarray(res.pruned_v)
-        n_emb = int(res.n_emb)
-        embF = np.asarray(res.emb_frontier)[:max(0, n_emb)]
-        embS = np.asarray(res.emb_slot)[:max(0, n_emb)]
-        t1 = time.perf_counter()
-        self.t_sync_s += t1 - t0
-        if (self.dispatch_timeout_s is not None
-                and t1 - rec.t_dispatch > self.dispatch_timeout_s):
-            self.fault_counters["hangs"] += 1
-            self._watchdog_fire({q.slot: q for q, *_ in rec.metas},
-                                "dispatch exceeded watchdog deadline "
-                                f"({self.dispatch_timeout_s:g}s)",
-                                stacks=False)
-            return
-        if self.validate_digests and not (
-                0 <= head <= tail <= self._ring_capacity
-                and 0 <= n_emb <= self._emb_cap):
-            # the ring digest has no per-slot blame: an out-of-bounds
-            # head/tail invalidates the whole dispatch
-            self.fault_counters["digest_failures"] += 1
-            self._watchdog_fire(
-                {q.slot: q for q, *_ in rec.metas},
-                f"megastep digest globally invalid (head={head} "
-                f"tail={tail} n_emb={n_emb})", stacks=False)
-            return
-        r0, f0 = self.t_retire_s, self.t_flush_s
+        with span(spans.SCHED_READBACK, self.t_sync):
+            head = int(res.head)
+            tail = int(res.tail)
+            bufF = np.asarray(res.buf_frontier)
+            bufU = np.asarray(res.buf_used)
+            bufP = np.asarray(res.buf_phi)
+            slot_a = np.asarray(res.buf_slot)
+            depth_a = np.asarray(res.buf_depth)
+            parent_a = np.asarray(res.buf_parent)
+            valid_a = np.asarray(res.buf_valid)
+            rempty = np.asarray(res.refined_empty)
+            nchild = np.asarray(res.n_children)
+            nleft = np.asarray(res.n_leftover)
+            leftover = np.asarray(res.leftover)
+            pmask = mask64(np.asarray(res.partial_mask))
+            nprun = np.asarray(res.n_pruned)
+            ninj = np.asarray(res.n_inj)
+            nembr = np.asarray(res.n_emb_row)
+            dstored = np.asarray(res.dev_stored)
+            pruned_v = np.asarray(res.pruned_v)
+            n_emb = int(res.n_emb)
+            embF = np.asarray(res.emb_frontier)[:max(0, n_emb)]
+            embS = np.asarray(res.emb_slot)[:max(0, n_emb)]
+        with span(spans.SCHED_DIGEST, (self.t_host, self.t_digest)):
+            if (self.dispatch_timeout_s is not None
+                    and time.perf_counter() - rec.t_dispatch
+                    > self.dispatch_timeout_s):
+                self.fault_counters["hangs"] += 1
+                self._watchdog_fire({q.slot: q for q, *_ in rec.metas},
+                                    "dispatch exceeded watchdog deadline "
+                                    f"({self.dispatch_timeout_s:g}s)",
+                                    stacks=False)
+                return
+            if self.validate_digests and not (
+                    0 <= head <= tail <= self._ring_capacity
+                    and 0 <= n_emb <= self._emb_cap):
+                # the ring digest has no per-slot blame: an out-of-bounds
+                # head/tail invalidates the whole dispatch
+                self.fault_counters["digest_failures"] += 1
+                self._watchdog_fire(
+                    {q.slot: q for q, *_ in rec.metas},
+                    f"megastep digest globally invalid (head={head} "
+                    f"tail={tail} n_emb={n_emb})", stacks=False)
+                return
 
-        # ---- Δ store accounting (digest counter lanes) -----------------
-        self._fold_store_counters(
-            (res.pat_stored, res.pat_overwrites, res.pat_evictions,
-             res.pat_dropped), rec.slot_map)
+            # ---- Δ store accounting (digest counter lanes) -----------------
+            self._fold_store_counters(
+                (res.pat_stored, res.pat_overwrites, res.pat_evictions,
+                 res.pat_dropped), rec.slot_map)
 
-        f_in = self.wave_size
-        slot_map = rec.slot_map
-        involved: dict[int, QueryState] = {}
-        sweeps: dict[int, list] = {}
-        # per-slot work accounting surfaced by the digest
-        self.slot_rows_expanded += np.asarray(res.slot_rows, np.int64)
-        self.slot_children_created += np.asarray(res.slot_children,
-                                                 np.int64)
-        # shard of every ring row: input rows from their pick's work
-        # item, in-loop rows inherit their parent's shard (parents
-        # always precede children, so K passes reach every chain)
-        shard_of = np.zeros(tail, np.int32)
+            f_in = self.wave_size
+            slot_map = rec.slot_map
+            involved: dict[int, QueryState] = {}
+            sweeps: dict[int, list] = {}
+            # per-slot work accounting surfaced by the digest
+            with span(spans.SCHED_READBACK):
+                self.slot_rows_expanded += np.asarray(res.slot_rows,
+                                                      np.int64)
+                self.slot_children_created += np.asarray(
+                    res.slot_children, np.int64)
+            # shard of every ring row: input rows from their pick's work
+            # item, in-loop rows inherit their parent's shard (parents
+            # always precede children, so K passes reach every chain)
+            shard_of = np.zeros(tail, np.int32)
 
-        # ---- 1) input-row bookkeeping (rows [0, f_in) of the ring) -----
-        for q, seg, s, e, woff, k, shard in rec.metas:
-            shard_of[woff:woff + k] = shard
-            if not q.active:
-                continue
-            involved[q.query_id] = q
-            sl = slice(woff, woff + k)
-            rows = slice(s, e)
-            seg.gamma[rows] |= pmask[sl]
-            seg.pending_leftover[rows] = leftover[sl]
-            seg.expanded[rows] = True
-            seg.stored[rows] |= dstored[sl]
-            seg.outstanding[rows] += nchild[sl]
-            seg.reported[rows] |= nembr[sl] > 0
-            q.stats.deadend_prunes += int(nprun[sl].sum())
-            q.stats.injectivity_fails += int(ninj[sl].sum())
-            q.stats.patterns_stored += int(dstored[sl].sum())
-            if (nleft[sl] > 0).any():
-                q.push(WorkItem(seg.seg_id, s, e, "leftover", shard))
-            sweeps.setdefault(q.query_id, []).append(
-                (seg, np.arange(s, e), rempty[sl]))
-
-        # ---- Δ hit counters (pruned-child lanes, any ring row) ---------
-        if any(q.hit_counts is not None for q in slot_map.values()):
-            for sl_v, q in slot_map.items():
-                if q.hit_counts is None:
-                    continue
-                rows = np.nonzero(slot_a[:tail] == sl_v)[0]
-                if len(rows):
-                    q.note_hits(depth_a[rows], pruned_v[rows])
-
-        # ---- 2) embeddings found in-loop (+ limit aborts) --------------
-        if n_emb:
-            for sl_v in np.unique(embS):
-                q = slot_map.get(int(sl_v))
-                if q is None or not q.active:
-                    continue
-                self._fold_embeddings(q, embF[embS == sl_v])
-                if q.limit is not None and q.stats.found >= q.limit:
-                    self._abort(q, "limit")
-
-        # ---- 3) rows created in-loop -> new segments -------------------
-        if tail > f_in:
-            # ring index -> (q-local segment id, row) for parent links;
-            # parents always precede children in the ring.
-            seg_of = np.full(tail, -1, np.int64)
-            row_of = np.full(tail, -1, np.int64)
+            # ---- 1) input-row bookkeeping (rows [0, f_in) of the ring) -----
             for q, seg, s, e, woff, k, shard in rec.metas:
-                seg_of[woff:woff + k] = seg.seg_id
-                row_of[woff:woff + k] = np.arange(s, e)
-            new_idx = np.arange(f_in, tail)
-            new_idx = new_idx[valid_a[f_in:tail]]
-            # propagate shards down parent chains (≤ K links deep) —
-            # skipped on the default path where every shard id is 0
-            if any(q.parallelism > 1 for q in slot_map.values()):
-                for _ in range(self.megastep_depth):
-                    shard_of[new_idx] = shard_of[parent_a[new_idx]]
-            sl_arr = slot_a[new_idx]
-            for sl_v in np.unique(sl_arr):
-                q = slot_map.get(int(sl_v))
-                qsel = new_idx[sl_arr == sl_v]
-                if q is None or not q.active:
+                shard_of[woff:woff + k] = shard
+                if not q.active:
                     continue
                 involved[q.query_id] = q
-                qd = depth_a[qsel]
-                qsh = shard_of[qsel]
-                for d_v in np.unique(qd):          # ascending: parents
-                    dsel = qsel[qd == d_v]         # precede children
-                    dsh = qsh[qd == d_v]
-                    for sh_v in np.unique(dsh):    # segments stay
-                        sel = dsel[dsh == sh_v]    # shard-pure
-                        exp_sel = sel[sel < head]
-                        sel2 = np.concatenate([exp_sel, sel[sel >= head]])
-                        r = len(sel2)
-                        n_exp = len(exp_sel)
-                        q.stats.rows_created += r
-                        cseg = q.new_segment(
-                            int(d_v), bufF[sel2], bufU[sel2], bufP[sel2],
-                            seg_of[parent_a[sel2]].astype(np.int32),
-                            row_of[parent_a[sel2]].astype(np.int32),
-                            shard=int(sh_v))
-                        cseg.expanded[:n_exp] = True
-                        cseg.gamma[:n_exp] = pmask[exp_sel]
-                        cseg.pending_leftover[:] = leftover[sel2]
-                        cseg.outstanding[:] = nchild[sel2]
-                        cseg.reported[:] = nembr[sel2] > 0
-                        cseg.stored[:] = dstored[sel2]
-                        q.stats.deadend_prunes += int(nprun[exp_sel].sum())
-                        q.stats.injectivity_fails += int(ninj[exp_sel].sum())
-                        q.stats.patterns_stored += int(dstored[sel2].sum())
-                        seg_of[sel2] = cseg.seg_id
-                        row_of[sel2] = np.arange(r)
-                        if n_exp < r:
-                            q.push(WorkItem(cseg.seg_id, n_exp, r, "fresh",
-                                            int(sh_v)))
-                        if n_exp and (nleft[exp_sel] > 0).any():
-                            q.push(WorkItem(cseg.seg_id, 0, n_exp,
-                                            "leftover", int(sh_v)))
-                        sweeps.setdefault(q.query_id, []).append(
-                            (cseg, np.arange(n_exp), rempty[exp_sel]))
+                sl = slice(woff, woff + k)
+                rows = slice(s, e)
+                seg.gamma[rows] |= pmask[sl]
+                seg.pending_leftover[rows] = leftover[sl]
+                seg.expanded[rows] = True
+                seg.stored[rows] |= dstored[sl]
+                seg.outstanding[rows] += nchild[sl]
+                seg.reported[rows] |= nembr[sl] > 0
+                q.stats.deadend_prunes += int(nprun[sl].sum())
+                q.stats.injectivity_fails += int(ninj[sl].sum())
+                q.stats.patterns_stored += int(dstored[sl].sum())
+                if (nleft[sl] > 0).any():
+                    q.push(WorkItem(seg.seg_id, s, e, "leftover", shard))
+                sweeps.setdefault(q.query_id, []).append(
+                    (seg, np.arange(s, e), rempty[sl]))
 
-        # ---- 4) Lemma-4 resolution sweep over every expanded row -------
-        for qid, q in involved.items():
-            if not q.active:
-                continue
-            items: list = []
-            for seg, srows, remask in sweeps.get(qid, []):
-                if seg.seg_id not in q.segments:
+            # ---- Δ hit counters (pruned-child lanes, any ring row) ---------
+            if any(q.hit_counts is not None for q in slot_map.values()):
+                for sl_v, q in slot_map.items():
+                    if q.hit_counts is None:
+                        continue
+                    rows = np.nonzero(slot_a[:tail] == sl_v)[0]
+                    if len(rows):
+                        q.note_hits(depth_a[rows], pruned_v[rows])
+
+            # ---- 2) embeddings found in-loop (+ limit aborts) --------------
+            if n_emb:
+                for sl_v in np.unique(embS):
+                    q = slot_map.get(int(sl_v))
+                    if q is None or not q.active:
+                        continue
+                    self._fold_embeddings(q, embF[embS == sl_v])
+                    if q.limit is not None and q.stats.found >= q.limit:
+                        self._abort(q, "limit")
+
+            # ---- 3) rows created in-loop -> new segments -------------------
+            if tail > f_in:
+                # ring index -> (q-local segment id, row) for parent links;
+                # parents always precede children in the ring.
+                seg_of = np.full(tail, -1, np.int64)
+                row_of = np.full(tail, -1, np.int64)
+                for q, seg, s, e, woff, k, shard in rec.metas:
+                    seg_of[woff:woff + k] = seg.seg_id
+                    row_of[woff:woff + k] = np.arange(s, e)
+                new_idx = np.arange(f_in, tail)
+                new_idx = new_idx[valid_a[f_in:tail]]
+                # propagate shards down parent chains (≤ K links deep) —
+                # skipped on the default path where every shard id is 0
+                if any(q.parallelism > 1 for q in slot_map.values()):
+                    for _ in range(self.megastep_depth):
+                        shard_of[new_idx] = shard_of[parent_a[new_idx]]
+                sl_arr = slot_a[new_idx]
+                for sl_v in np.unique(sl_arr):
+                    q = slot_map.get(int(sl_v))
+                    qsel = new_idx[sl_arr == sl_v]
+                    if q is None or not q.active:
+                        continue
+                    involved[q.query_id] = q
+                    qd = depth_a[qsel]
+                    qsh = shard_of[qsel]
+                    for d_v in np.unique(qd):          # ascending: parents
+                        dsel = qsel[qd == d_v]         # precede children
+                        dsh = qsh[qd == d_v]
+                        for sh_v in np.unique(dsh):    # segments stay
+                            sel = dsel[dsh == sh_v]    # shard-pure
+                            exp_sel = sel[sel < head]
+                            sel2 = np.concatenate([exp_sel, sel[sel >= head]])
+                            r = len(sel2)
+                            n_exp = len(exp_sel)
+                            q.stats.rows_created += r
+                            cseg = q.new_segment(
+                                int(d_v), bufF[sel2], bufU[sel2], bufP[sel2],
+                                seg_of[parent_a[sel2]].astype(np.int32),
+                                row_of[parent_a[sel2]].astype(np.int32),
+                                shard=int(sh_v))
+                            cseg.expanded[:n_exp] = True
+                            cseg.gamma[:n_exp] = pmask[exp_sel]
+                            cseg.pending_leftover[:] = leftover[sel2]
+                            cseg.outstanding[:] = nchild[sel2]
+                            cseg.reported[:] = nembr[sel2] > 0
+                            cseg.stored[:] = dstored[sel2]
+                            q.stats.deadend_prunes += int(nprun[exp_sel].sum())
+                            q.stats.injectivity_fails += int(
+                                ninj[exp_sel].sum())
+                            q.stats.patterns_stored += int(dstored[sel2].sum())
+                            seg_of[sel2] = cseg.seg_id
+                            row_of[sel2] = np.arange(r)
+                            if n_exp < r:
+                                q.push(WorkItem(cseg.seg_id, n_exp, r, "fresh",
+                                                int(sh_v)))
+                            if n_exp and (nleft[exp_sel] > 0).any():
+                                q.push(WorkItem(cseg.seg_id, 0, n_exp,
+                                                "leftover", int(sh_v)))
+                            sweeps.setdefault(q.query_id, []).append(
+                                (cseg, np.arange(n_exp), rempty[exp_sel]))
+
+            # ---- 4) Lemma-4 resolution sweep over every expanded row -------
+            for qid, q in involved.items():
+                if not q.active:
                     continue
-                unres = ~seg.resolved[srows]
-                for row in srows[remask & unres]:
-                    # Lemma 1: Γ = N(u_d) ∩ dom(M̂)
-                    gam = q.qnbr_bits[seg.depth] & below(seg.depth)
-                    items.append((seg.seg_id, int(row), False, gam))
-                cand = srows[~remask & unres]
-                if len(cand):
-                    done = cand[(seg.outstanding[cand] == 0)
-                                & seg.expanded[cand]
-                                & ~seg.pending_leftover[cand].any(axis=1)]
-                    for row in done:
-                        if seg.reported[row]:
-                            items.append((seg.seg_id, int(row), True,
-                                          np.uint64(0)))
-                        else:
-                            items.append(q.finalize_row(seg, int(row)))
-            q.resolve_rows(items)
-            if q.max_rows is not None and q.stats.rows_created > q.max_rows:
-                self._abort(q, "rows")
-            elif not q.segments:
-                self._finish(q)
-        self._note_prunes(int(nprun[:tail].sum()), max(0, tail - f_in))
-        dt = time.perf_counter() - t1
-        self.t_host_s += dt
-        self.t_digest_s += max(0.0, dt - (self.t_retire_s - r0)
-                               - (self.t_flush_s - f0))
+                items: list = []
+                for seg, srows, remask in sweeps.get(qid, []):
+                    if seg.seg_id not in q.segments:
+                        continue
+                    unres = ~seg.resolved[srows]
+                    for row in srows[remask & unres]:
+                        # Lemma 1: Γ = N(u_d) ∩ dom(M̂)
+                        gam = q.qnbr_bits[seg.depth] & below(seg.depth)
+                        items.append((seg.seg_id, int(row), False, gam))
+                    cand = srows[~remask & unres]
+                    if len(cand):
+                        done = cand[(seg.outstanding[cand] == 0)
+                                    & seg.expanded[cand]
+                                    & ~seg.pending_leftover[cand].any(axis=1)]
+                        for row in done:
+                            if seg.reported[row]:
+                                items.append((seg.seg_id, int(row), True,
+                                              np.uint64(0)))
+                            else:
+                                items.append(q.finalize_row(seg, int(row)))
+                q.resolve_rows(items)
+                if (q.max_rows is not None
+                        and q.stats.rows_created > q.max_rows):
+                    self._abort(q, "rows")
+                elif not q.segments:
+                    self._finish(q)
+            self._note_prunes(int(nprun[:tail].sum()), max(0, tail - f_in))
 
     # ------------------------------------------------------------------
     # leftover extraction dispatch / retire (single-step program)
@@ -2013,33 +2025,28 @@ class WaveScheduler:
         return _Inflight("leftover", res, metas, slot_map,
                          fr=fr, us=us, ph=ph, depth_v=depth_v)
 
+    @spans.traced(spans.SCHED_RETIRE_WAVE)
     def _retire_leftover(self, rec: _Inflight) -> None:
         res = rec.res
-        t0 = time.perf_counter()
-        child_v = np.asarray(res[0])
-        child_valid = np.asarray(res[1])
-        leftover = np.asarray(res[2])
-        n_leftover = np.asarray(res[3])
-        partial = mask64(np.asarray(res[4]))
-        n_pruned = np.asarray(res[5])
-        pruned_v = np.asarray(res[6])
-        t1 = time.perf_counter()
-        self.t_sync_s += t1 - t0
-        r0, f0 = self.t_retire_s, self.t_flush_s
-        f_pad = self.wave_size
-        digest = dict(
-            refined_empty=np.zeros(f_pad, bool),
-            n_children=child_valid.sum(axis=1).astype(np.int32),
-            n_leftover=n_leftover, partial=partial, child_v=child_v,
-            child_valid=child_valid, leftover=leftover,
-            n_pruned=n_pruned, n_inj=np.zeros(f_pad, np.int32),
-            pruned_v=pruned_v)
-        self._process_wave("leftover", rec.metas, rec.fr, rec.us, rec.ph,
-                           rec.depth_v, digest)
-        dt = time.perf_counter() - t1
-        self.t_host_s += dt
-        self.t_digest_s += max(0.0, dt - (self.t_retire_s - r0)
-                               - (self.t_flush_s - f0))
+        with span(spans.SCHED_READBACK, self.t_sync):
+            child_v = np.asarray(res[0])
+            child_valid = np.asarray(res[1])
+            leftover = np.asarray(res[2])
+            n_leftover = np.asarray(res[3])
+            partial = mask64(np.asarray(res[4]))
+            n_pruned = np.asarray(res[5])
+            pruned_v = np.asarray(res[6])
+        with span(spans.SCHED_DIGEST, (self.t_host, self.t_digest)):
+            f_pad = self.wave_size
+            digest = dict(
+                refined_empty=np.zeros(f_pad, bool),
+                n_children=child_valid.sum(axis=1).astype(np.int32),
+                n_leftover=n_leftover, partial=partial, child_v=child_v,
+                child_valid=child_valid, leftover=leftover,
+                n_pruned=n_pruned, n_inj=np.zeros(f_pad, np.int32),
+                pruned_v=pruned_v)
+            self._process_wave("leftover", rec.metas, rec.fr, rec.us, rec.ph,
+                               rec.depth_v, digest)
 
     # ------------------------------------------------------------------
     # single-step wave processing (megastep_depth == 1 reference path,
@@ -2050,58 +2057,51 @@ class WaveScheduler:
         if picks is None:
             return False
         kind = self._wave_kind
-        t0 = time.perf_counter()
-        fr, us, ph, lo, valid, slot_v, depth_v, metas = \
-            self._build_wave(picks, kind)
-        self._flush_stores()
-        for q in {q.slot: q for q, *_ in metas}.values():
-            q.stats.waves += 1
-
-        if kind == "fresh":
-            self.slot_rows_expanded += np.bincount(
-                slot_v[valid], minlength=self.n_slots).astype(np.int64)
-            res, self.tb = expand_wave_mq(
-                self.g, self.qb, self.tb, fr, us, ph, valid, slot_v,
-                depth_v, kpr=self.kpr, backend=self._kernel_backend,
-                block_f=self._block_f, dma_depth=self._dma_depth)
-            self.t_dispatch_s += time.perf_counter() - t0
-            t1 = time.perf_counter()
-            digest = dict(
-                refined_empty=np.asarray(res.refined_empty),
-                n_children=np.asarray(res.n_children),
-                n_leftover=np.asarray(res.n_leftover),
-                partial=mask64(np.asarray(res.partial_mask)),
-                child_v=np.asarray(res.child_v),
-                child_valid=np.asarray(res.child_valid),
-                leftover=np.asarray(res.leftover),
-                n_pruned=np.asarray(res.n_pruned),
-                n_inj=np.asarray(res.n_inj),
-                pruned_v=np.asarray(res.pruned_v))
-        else:
-            res = extract_more_mq(self.tb, ph, slot_v, depth_v, lo,
-                                  kpr=4 * self.kpr)
-            self.tb = res[7]        # handle with hit counters bumped
-            self.t_dispatch_s += time.perf_counter() - t0
-            t1 = time.perf_counter()
-            child_valid = np.asarray(res[1])
-            digest = dict(
-                refined_empty=np.zeros(self.wave_size, bool),
-                n_children=child_valid.sum(axis=1).astype(np.int32),
-                n_leftover=np.asarray(res[3]),
-                partial=mask64(np.asarray(res[4])),
-                child_v=np.asarray(res[0]), child_valid=child_valid,
-                leftover=np.asarray(res[2]),
-                n_pruned=np.asarray(res[5]),
-                n_inj=np.zeros(self.wave_size, np.int32),
-                pruned_v=np.asarray(res[6]))
-        t2 = time.perf_counter()
-        self.t_sync_s += t2 - t1
-        r0, f0 = self.t_retire_s, self.t_flush_s
-        self._process_wave(kind, metas, fr, us, ph, depth_v, digest)
-        dt = time.perf_counter() - t2
-        self.t_host_s += dt
-        self.t_digest_s += max(0.0, dt - (self.t_retire_s - r0)
-                               - (self.t_flush_s - f0))
+        with span(spans.SCHED_DISPATCH_WAVE, self.t_dispatch):
+            fr, us, ph, lo, valid, slot_v, depth_v, metas = \
+                self._build_wave(picks, kind)
+            self._flush_stores()
+            for q in {q.slot: q for q, *_ in metas}.values():
+                q.stats.waves += 1
+            if kind == "fresh":
+                self.slot_rows_expanded += np.bincount(
+                    slot_v[valid], minlength=self.n_slots).astype(np.int64)
+                res, self.tb = expand_wave_mq(
+                    self.g, self.qb, self.tb, fr, us, ph, valid, slot_v,
+                    depth_v, kpr=self.kpr, backend=self._kernel_backend,
+                    block_f=self._block_f, dma_depth=self._dma_depth)
+            else:
+                res = extract_more_mq(self.tb, ph, slot_v, depth_v, lo,
+                                      kpr=4 * self.kpr)
+                self.tb = res[7]    # handle with hit counters bumped
+        with span(spans.SCHED_RETIRE_WAVE):
+            with span(spans.SCHED_READBACK, self.t_sync):
+                if kind == "fresh":
+                    digest = dict(
+                        refined_empty=np.asarray(res.refined_empty),
+                        n_children=np.asarray(res.n_children),
+                        n_leftover=np.asarray(res.n_leftover),
+                        partial=mask64(np.asarray(res.partial_mask)),
+                        child_v=np.asarray(res.child_v),
+                        child_valid=np.asarray(res.child_valid),
+                        leftover=np.asarray(res.leftover),
+                        n_pruned=np.asarray(res.n_pruned),
+                        n_inj=np.asarray(res.n_inj),
+                        pruned_v=np.asarray(res.pruned_v))
+                else:
+                    child_valid = np.asarray(res[1])
+                    digest = dict(
+                        refined_empty=np.zeros(self.wave_size, bool),
+                        n_children=child_valid.sum(axis=1).astype(np.int32),
+                        n_leftover=np.asarray(res[3]),
+                        partial=mask64(np.asarray(res[4])),
+                        child_v=np.asarray(res[0]), child_valid=child_valid,
+                        leftover=np.asarray(res[2]),
+                        n_pruned=np.asarray(res[5]),
+                        n_inj=np.zeros(self.wave_size, np.int32),
+                        pruned_v=np.asarray(res[6]))
+            with span(spans.SCHED_DIGEST, (self.t_host, self.t_digest)):
+                self._process_wave(kind, metas, fr, us, ph, depth_v, digest)
         return True
 
     def _process_wave(self, kind: str, metas: list, fr, us, ph, depth_v,
@@ -2139,11 +2139,12 @@ class WaveScheduler:
             cf, cu, cp, par, cvalid = assemble_children_mq(
                 fr, us, ph, np.where(child_valid_eff, child_v, -1),
                 child_valid_eff, depth_v, np.int32(id_base))
-            cf = np.asarray(cf)
-            cu = np.asarray(cu)
-            cp = np.asarray(cp)
-            par = np.asarray(par)
-            cvalid = np.asarray(cvalid)
+            with span(spans.SCHED_READBACK):
+                cf = np.asarray(cf)
+                cu = np.asarray(cu)
+                cp = np.asarray(cp)
+                par = np.asarray(par)
+                cvalid = np.asarray(cvalid)
             self._reset_learning_on_overflow()
 
         # ---- per-item host bookkeeping ---------------------------------
@@ -2246,8 +2247,9 @@ class WaveScheduler:
         """Aggregate wave statistics for SLO / occupancy reporting.
         Prune/row totals include still-active queries, so mid-run polling
         sees live numbers."""
-        self._materialize_flush_counters()
-        occupancy = np.asarray(self.tb.valid.sum(axis=1), np.int64)
+        with span(spans.METRICS_READBACK):
+            self._materialize_flush_counters()
+            occupancy = np.asarray(self.tb.valid.sum(axis=1), np.int64)
         prunes = self.total_prunes + sum(
             q.stats.deadend_prunes for q in self.pool.active_queries())
         rows = self.total_rows_created + sum(
@@ -2274,16 +2276,20 @@ class WaveScheduler:
             "deadend_prunes": prunes,
             "rows_created": rows,
             "prune_rate": prunes / max(1, prunes + rows),
-            "dispatch_time_s": self.t_dispatch_s,
-            "device_sync_time_s": self.t_sync_s,
-            "host_time_s": self.t_host_s,
-            # disjoint host-time breakdown (ISSUE 6): where host wall
-            # actually goes — digest folding, admission, retirement
-            # (_finish), Δ pattern flushing
-            "host_admission_time_s": self.t_admit_s,
-            "host_digest_time_s": self.t_digest_s,
-            "host_retirement_time_s": self.t_retire_s,
-            "host_flush_time_s": self.t_flush_s,
+            "dispatch_time_s": self.t_dispatch.s,
+            "device_sync_time_s": self.t_sync.s,
+            "host_time_s": self.t_host.s,
+            # disjoint host-time breakdown: where host wall actually
+            # goes — digest folding, admission, retirement (_finish),
+            # Δ pattern flushing
+            "host_admission_time_s": self.t_admit.s,
+            "host_digest_time_s": self.t_digest.s,
+            "host_retirement_time_s": self.t_retire.s,
+            "host_flush_time_s": self.t_flush.s,
+            # candidate filtering and ordering at submit, and the queries
+            # that paid it
+            "host_prepare_time_s": self.t_prepare.s,
+            "prepared": self.prepared,
             "device_stacks": self._use_device,
             # adjacency layout (DESIGN.md §2): which refine variant this
             # engine compiled ("dense-vmem" | "hier-hbm") and what the

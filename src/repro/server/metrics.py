@@ -14,7 +14,10 @@ JSON-safe snapshot:
 * **engine SLO + scheduler stats** — ``QueryServer.slo_report()``
   (latency/TTFE percentiles, terminal-status tallies, and the
   ``queue_depth``/``resident_queries`` gauges) and
-  ``scheduler_stats()`` (fault counters, tuning record, occupancy).
+  ``scheduler_stats()`` (fault counters, tuning record, occupancy),
+  with the server's queue time: ``queue_wait_s`` summed over the
+  ``queue_waits`` requests that took an engine slot, each from its
+  acceptance to its admission into a slot.
 
 The engine-side report is refreshed *by the engine thread* (the
 scheduler is single-threaded state; ``scheduler_stats`` mutates flush
@@ -50,6 +53,8 @@ class ServerMetrics:
         self._counters = {k: 0 for k in _COUNTERS}
         self._engine_report: dict = {}
         self._engine_report_t = 0.0
+        self._queue_wait_s = 0.0
+        self._queue_waits = 0
         self.t_start = time.time()
         self.draining = False
 
@@ -62,12 +67,22 @@ class ServerMetrics:
         with self._lock:
             return self._counters[name]
 
+    def note_queue_wait(self, seconds: float) -> None:
+        """Engine thread: a request took an engine slot ``seconds`` after
+        the server accepted it."""
+        with self._lock:
+            self._queue_wait_s += seconds
+            self._queue_waits += 1
+
     # ------------------------------------------------------------------
     def set_engine_report(self, report: dict) -> None:
-        """Engine-thread-only: cache the latest slo_report/stats merge
-        so HTTP threads never touch live scheduler state."""
+        """Engine-thread-only: cache the latest slo_report/stats merge,
+        with the queue-time counters as of the same moment, so HTTP
+        threads never touch live scheduler state."""
         with self._lock:
-            self._engine_report = report
+            self._engine_report = dict(report,
+                                       queue_wait_s=self._queue_wait_s,
+                                       queue_waits=self._queue_waits)
             self._engine_report_t = time.time()
 
     # ------------------------------------------------------------------

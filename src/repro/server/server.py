@@ -43,6 +43,8 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 import numpy as np
 
 from ..api.handle import MatchHandle
+from ..core import spans
+from ..core.spans import span
 from ..core.vectorized import QueueFull
 from ..serving.query_server import QueryServer
 from .admission import AdmissionController
@@ -75,7 +77,7 @@ class _ServeRequest:
 
     __slots__ = ("wire", "query_id", "priority", "events", "handle",
                  "n_sent", "seq", "cancel_requested", "t_accept",
-                 "options")
+                 "options", "admitted")
 
     def __init__(self, wire: protocol.MatchRequestWire, query_id: int):
         self.wire = wire
@@ -88,6 +90,7 @@ class _ServeRequest:
         self.cancel_requested = False
         self.t_accept = time.perf_counter()
         self.options: dict = dict(wire.options)
+        self.admitted = False      # queue wait noted (took a slot)
 
     # terminal results for requests that never reached the engine ------
     def _terminal(self, status: str, **extra) -> dict:
@@ -327,14 +330,16 @@ class MatchServer:
         session = self.qserver.session
         t_drain_start = None
         while True:
-            did = self._admit_ready()
+            with span(spans.SERVER_ADMIT_READY):
+                did = self._admit_ready()
             if not session.idle:
                 try:
                     did = session.step() or did
                 except Exception as e:      # noqa: BLE001 — stop serving
                     self._engine_failed(e)
                     return
-            did = self._deliver() or did
+            with span(spans.SERVER_DELIVER):
+                did = self._deliver() or did
             now = time.perf_counter()
             if now - self._t_report >= self.args.metrics_refresh_s:
                 self._refresh_report()
@@ -352,7 +357,8 @@ class MatchServer:
                     self._drained.set()
                     return
             if not did:
-                self._work.wait(timeout=self.args.idle_poll_s)
+                with span(spans.SERVER_WAIT):
+                    self._work.wait(timeout=self.args.idle_poll_s)
                 self._work.clear()
 
     def _engine_failed(self, exc: Exception) -> None:
@@ -419,6 +425,9 @@ class MatchServer:
         for qid in list(self._live):
             req = self._live[qid]
             h = req.handle
+            if not req.admitted and h.t_admit is not None:
+                req.admitted = True
+                self.metrics.note_queue_wait(h.t_admit - req.t_accept)
             if req.cancel_requested and not h.done():
                 h.cancel()             # scheduler eviction path
             while h._batches:
@@ -470,7 +479,9 @@ class MatchServer:
         """Engine-thread-only: snapshot the SLO report for /slo and
         /metrics (``scheduler_stats`` mutates scheduler state, so HTTP
         threads must never call it live)."""
-        self.metrics.set_engine_report(_jsonify(self.qserver.slo_report()))
+        with span(spans.SERVER_REPORT):
+            self.metrics.set_engine_report(
+                _jsonify(self.qserver.slo_report()))
         self._t_report = time.perf_counter()
 
 
