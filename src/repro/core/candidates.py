@@ -66,32 +66,34 @@ def _refine_once(query: Graph, data: Graph,
     neighbor that is a candidate of u'. Returns True if anything changed.
     """
     changed = False
-    nnz = data.indices.size
-    # one reduceat over the data CSR per (u, u') pair instead of a
-    # Python loop over candidates — the per-vertex generator dominated
-    # submit latency on the serving path. The segment sum counts a
-    # vertex's neighbors that are candidates of u'; empty rows read a
-    # garbage segment and are masked via ``nonempty``.
-    starts = np.minimum(data.indptr[:-1], max(nnz - 1, 0))
-    nonempty = (data.indptr[1:] - data.indptr[:-1]) > 0
+    indptr = data.indptr
     for u in range(query.n):
         mask_u = cand_masks[u]
-        if not mask_u.any():
+        idx = np.flatnonzero(mask_u)
+        nbrs_u = query.neighbors(u)
+        if idx.size == 0 or nbrs_u.size == 0:
             continue
-        keep = mask_u.copy()
-        for uq in query.neighbors(u):
-            if nnz == 0:
-                keep[:] = False
-                break
-            m_other = cand_masks[int(uq)]
-            # v survives iff any neighbor of v is in m_other
-            hit = np.add.reduceat(m_other[data.indices], starts) > 0
-            keep &= nonempty & hit
+        # the CSR rows of u's candidates only, gathered once: the sweep
+        # costs the sum of their degrees per query edge, not the whole
+        # adjacency. A candidate of degree 0 has no neighbor to offer.
+        row_start = indptr[idx].astype(np.int64)
+        deg = indptr[idx + 1].astype(np.int64) - row_start
+        keep = deg > 0
+        seg_start = np.cumsum(deg) - deg
+        flat = (np.repeat(row_start - seg_start, deg)
+                + np.arange(int(deg.sum()), dtype=np.int64))
+        nb = data.indices[flat]
+        live = np.flatnonzero(keep)
+        live_start = seg_start[live]
+        for uq in nbrs_u:
             if not keep.any():
                 break
-        if not np.array_equal(keep, mask_u):
+            # v survives iff any neighbor of v is a candidate of u'
+            hit = np.logical_or.reduceat(cand_masks[int(uq)][nb], live_start)
+            keep[live] &= hit
+        if not keep.all():
             changed = True
-            cand_masks[u] = keep
+            mask_u[idx[~keep]] = False
     return changed
 
 

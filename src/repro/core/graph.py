@@ -222,6 +222,7 @@ class Graph:
         object.__setattr__(self, "_bitmap", None)
         object.__setattr__(self, "_hier", {})
         object.__setattr__(self, "_label_index", None)
+        object.__setattr__(self, "_nbr_label_counts", None)
 
     @property
     def degrees(self) -> np.ndarray:
@@ -299,11 +300,18 @@ class Graph:
     # ---- neighbor label multiset signature (GraphQL-style filter) ------
     @property
     def neighbor_label_counts(self) -> np.ndarray:
-        """[n, n_labels] int32 — count of each label among neighbors."""
-        counts = np.zeros((self.n, self.n_labels), dtype=np.int32)
-        src = np.repeat(np.arange(self.n, dtype=np.int32), self.degrees)
-        np.add.at(counts, (src, self.labels[self.indices]), 1)
-        return counts
+        """[n, n_labels] int32 — count of each label among neighbors.
+
+        Built once per graph and read-only: every query's NLF filter
+        reads the data graph's table."""
+        if self._nbr_label_counts is None:
+            src = np.repeat(np.arange(self.n, dtype=np.int64), self.degrees)
+            cell = src * self.n_labels + self.labels[self.indices]
+            counts = np.bincount(cell, minlength=self.n * self.n_labels)
+            counts = counts.astype(np.int32).reshape(self.n, self.n_labels)
+            counts.setflags(write=False)
+            object.__setattr__(self, "_nbr_label_counts", counts)
+        return self._nbr_label_counts
 
     def relabel(self, order: np.ndarray) -> "Graph":
         """A copy with vertex ``order[i]`` renamed to ``i`` (``order``
